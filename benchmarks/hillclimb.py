@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 
 EXPERIMENTS = {
     # ---------------- mamba2-1.3b x train_4k (collective-bound) ----------
@@ -192,11 +191,8 @@ def screen(names, json_out: str | None = None, *, jobs=None,
 
 
 def run(exp_name: str, json_out: str | None = None):
-    # dryrun import must happen in a fresh process normally; here we are
-    # the main module so set flags first
-    import os
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=512")
+    """Lower one experiment's cell; needs the dry run's placeholder devices
+    (``dryrun.use_host_devices()`` before the process's first JAX op)."""
     from repro.launch import dryrun
 
     exp = EXPERIMENTS[exp_name]
@@ -251,6 +247,8 @@ def main():
         screen(names, args.json, jobs=args.jobs,
                chunk_size=args.chunk_size or None)
         return
+    from repro.launch import dryrun
+    dryrun.use_host_devices()
     for n in names:
         run(n, args.json)
 

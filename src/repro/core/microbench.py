@@ -14,6 +14,7 @@ seconds on CI.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Tuple
 
@@ -77,6 +78,16 @@ DEFAULT_REPEATS = 15
 DEFAULT_WARMUPS = 3
 
 
+def _on_host(fn):
+    """Run ``fn`` on the host CPU device, whatever the default device is:
+    its results are registered as the host's (``cpu_host*``)."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_device(jax.devices("cpu")[0]):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 def _timed(fn: Callable[[], jax.Array], *, repeats: int, warmups: int
            ) -> float:
     def run():
@@ -85,6 +96,7 @@ def _timed(fn: Callable[[], jax.Array], *, repeats: int, warmups: int
     return med
 
 
+@_on_host
 def measure_matmul_flops(n: int = 1024, *, dtype=jnp.float32,
                          repeats: int = DEFAULT_REPEATS,
                          warmups: int = DEFAULT_WARMUPS) -> float:
@@ -98,6 +110,7 @@ def measure_matmul_flops(n: int = 1024, *, dtype=jnp.float32,
     return 2.0 * n ** 3 / t
 
 
+@_on_host
 def measure_stream_bandwidth(nbytes: int = 1 << 26, *,
                              repeats: int = DEFAULT_REPEATS,
                              warmups: int = DEFAULT_WARMUPS) -> float:
@@ -111,6 +124,7 @@ def measure_stream_bandwidth(nbytes: int = 1 << 26, *,
     return 2.0 * nbytes / t
 
 
+@_on_host
 def measure_launch_latency(*, repeats: int = 50,
                            warmups: int = 10) -> float:
     """Dispatch overhead: time an O(1) jitted program."""
@@ -120,6 +134,7 @@ def measure_launch_latency(*, repeats: int = 50,
     return _timed(lambda: f(x), repeats=repeats, warmups=warmups)
 
 
+@_on_host
 def measure_vector_flops(n: int = 1 << 22, *,
                          repeats: int = DEFAULT_REPEATS,
                          warmups: int = DEFAULT_WARMUPS) -> float:
@@ -171,6 +186,7 @@ def calibrate_host(*, quick: bool = True) -> HardwareParams:
 # Mirrors the paper's workload classes (Table IX).
 # ---------------------------------------------------------------------------
 
+@_on_host
 def host_suite(*, quick: bool = True):
     """Returns (workloads, measured_seconds, runnables) for the CPU host.
 

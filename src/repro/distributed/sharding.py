@@ -22,27 +22,10 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Callable, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    ``check_vma`` is the renamed ``check_rep`` — forward it to whichever
-    spelling this JAX build understands.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 TP_LAST = {"wq", "wk", "wv", "wg", "wu", "wi", "w_router", "w_dkv", "w_uk",
            "w_uv", "w_qa", "w_qb", "lm_head", "w_gates", "w_in", "wx", "wy",
@@ -108,6 +91,11 @@ def use_mesh(mesh: Mesh, rules: Optional[dict] = None):
     finally:
         _ACTIVE_MESH.reset(tok_m)
         _RULES.reset(tok_r)
+
+
+def active_mesh() -> Optional[Mesh]:
+    """The mesh installed by the innermost ``use_mesh``, else None."""
+    return _ACTIVE_MESH.get()
 
 
 def current_rules() -> Optional[dict]:

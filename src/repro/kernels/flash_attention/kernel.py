@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 128
 NEG_INF = -1e30
@@ -136,7 +134,7 @@ def mha(q, k, v, *, sm_scale: float, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),      # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),      # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,12 +1,10 @@
 """Public flash-attention op: kernel on TPU, interpret-mode kernel on CPU,
-with an XLA fallback for shapes the kernel does not tile well."""
+where shapes the kernel does not tile run the reference instead."""
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
-
+from ..device import resolve_interpret, use_reference
 from . import kernel, ref
 
 
@@ -22,10 +20,10 @@ def flash_attention(q, k, v, *, sm_scale: Optional[float] = None,
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = resolve_interpret(interpret)
     s = q.shape[2]
-    if not use_kernel or s % 8 != 0:
+    if not use_kernel or use_reference(s % 8 == 0, interpret,
+                                       f"flash_attention seq {s}"):
         return ref.attention(q, k, v, sm_scale=sm_scale, causal=causal,
                              window=window)
     return kernel.mha(q, k, v, sm_scale=sm_scale, causal=causal,

@@ -7,19 +7,18 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
-
+from ..device import resolve_interpret, use_reference
 from . import kernel, ref
 
 
 def matmul(a, b, *, bm: int = kernel.DEFAULT_BM, bn: int = kernel.DEFAULT_BN,
            bk: int = kernel.DEFAULT_BK, use_kernel: bool = True,
            interpret: Optional[bool] = None, out_dtype=None):
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = resolve_interpret(interpret)
     m, k = a.shape
     n = b.shape[1]
-    if not use_kernel or min(m, n, k) < 8:
+    if not use_kernel or use_reference(min(m, n, k) >= 8, interpret,
+                                       f"matmul {m}x{k}x{n}"):
         return ref.matmul(a, b, out_dtype=out_dtype)
     return kernel.matmul_tiled(a, b, bm=bm, bn=bn, bk=bk,
                                interpret=interpret, out_dtype=out_dtype)

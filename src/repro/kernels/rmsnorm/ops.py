@@ -3,18 +3,17 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
+from ..device import resolve_interpret, use_reference
 from . import kernel, ref
 
 
 def rmsnorm(x, w, *, eps: float = 1e-6, use_kernel: bool = True,
             interpret: Optional[bool] = None):
     """x: (..., D), w: (D,)."""
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = resolve_interpret(interpret)
     d = x.shape[-1]
-    if not use_kernel or x.ndim < 2 or d % 8 != 0:
+    if not use_kernel or use_reference(x.ndim >= 2 and d % 8 == 0,
+                                       interpret, f"rmsnorm {x.shape}"):
         return ref.rmsnorm(x, w, eps=eps)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, d)

@@ -13,9 +13,16 @@ Grid: (batch, heads, num_chunks) with the chunk axis SEQUENTIAL
 ("arbitrary") so the inter-chunk state lives in VMEM scratch.  B and C are
 shared across heads (ngroups=1, Mamba2 default).
 
-VMEM per step (fp32, L=128, P=64, N=128):
-    x,y (L,P) 32 KB each | B,C (L,N) 64 KB each | CB,Lmat (L,L) 64 KB each
-    | h scratch (N,P) 32 KB  — trivially VMEM-resident.
+Layout: the wrapper moves heads ahead of the sequence, so every block's
+last two dims are (8, 128)-tileable or whole: x/y blocks are (L, P), dt
+arrives as a column (L, 1) and a row (1, L), and A sits in SMEM, indexed
+by the head's program id.  The cumulative decay g = cumsum(dt*A) is built
+in both orientations as masked sums over the (L, L) tile, so the kernel
+needs no cumsum or transpose.
+
+VMEM per step (fp32, L=256, P=64, N=128):
+    x,y (L,P) 64 KB each | B,C (L,N) 128 KB each | CB,Lmat (L,L) 256 KB
+    each | h scratch (N,P) 32 KB  -- well inside VMEM.
 
 Stability: A < 0 and dt > 0 => all exponents <= 0, every exp() <= 1.
 """
@@ -28,48 +35,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 DEFAULT_CHUNK = 128
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_scr, *,
-                chunk: int):
+def _ssd_kernel(x_ref, dt_col_ref, dt_row_ref, a_ref, b_ref, c_ref, y_ref,
+                h_scr, *, chunk: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (L, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (L,)
-    a = a_ref[0].astype(jnp.float32)                 # scalar (negative)
+    a = a_ref[pl.program_id(1)]                      # scalar (negative)
+    x = x_ref[0, 0].astype(jnp.float32)              # (L, P)
+    dt_col = dt_col_ref[0, 0].astype(jnp.float32)    # (L, 1)
+    dt_row = dt_row_ref[0, 0].astype(jnp.float32)    # (1, L)
     b = b_ref[0].astype(jnp.float32)                 # (L, N)
     c = c_ref[0].astype(jnp.float32)                 # (L, N)
 
-    dta = dt * a                                     # (L,), <= 0
-    g = jnp.cumsum(dta)                              # (L,)
+    i_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    j_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = j_ids <= i_ids
+    # g = cumsum(dt * A), <= 0: as a column g_i and as a row g_j
+    g_col = a * jnp.sum(jnp.where(causal, dt_row, 0.0), axis=1,
+                        keepdims=True)               # (L, 1)
+    g_row = a * jnp.sum(jnp.where(i_ids <= j_ids, dt_col, 0.0), axis=0,
+                        keepdims=True)               # (1, L)
+    g_last = a * jnp.sum(dt_row, axis=1, keepdims=True)   # (1, 1)
 
     # inter-chunk: y_i += exp(g_i) * (C_i @ h_prev)
     h_prev = h_scr[...]                              # (N, P)
-    decay_out = jnp.exp(g)[:, None]                  # (L, 1)
-    y_inter = (c * decay_out) @ h_prev               # (L, P)
+    y_inter = jnp.dot(c * jnp.exp(g_col), h_prev,
+                      preferred_element_type=jnp.float32)  # (L, P)
 
     # intra-chunk: y_i += sum_{j<=i} exp(g_i - g_j) (C_i.B_j) dt_j x_j
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (L, L)
-    i_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    j_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lmat = jnp.where(j_ids <= i_ids,
-                     jnp.exp(g[:, None] - g[None, :]), 0.0)
-    y_intra = (cb * lmat) @ (dt[:, None] * x)        # (L, P)
+    # mask BEFORE exp: above the diagonal g_i - g_j > 0 and may overflow
+    lmat = jnp.exp(jnp.where(causal, g_col - g_row, -1e30))
+    y_intra = jnp.dot(cb * lmat, dt_col * x,
+                      preferred_element_type=jnp.float32)  # (L, P)
 
-    y_ref[0, :, 0, :] = (y_inter + y_intra).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_inter + y_intra).astype(y_ref.dtype)
 
     # state update: h = exp(g_last) h_prev + sum_j exp(g_last - g_j) dt_j B_j x_j^T
-    decay_state = jnp.exp(g[-1] - g)[:, None]        # (L, 1)
-    bw = b * decay_state * dt[:, None]               # (L, N)
-    h_scr[...] = jnp.exp(g[-1]) * h_prev + jax.lax.dot_general(
+    bw = b * (jnp.exp(g_last - g_col) * dt_col)     # (L, N)
+    h_scr[...] = jnp.exp(g_last) * h_prev + jax.lax.dot_general(
         bw, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (N, P)
 
@@ -86,27 +97,32 @@ def ssd(x, dt, a_log, b, c, *, chunk: int = DEFAULT_CHUNK,
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
     a = -jnp.exp(a_log.astype(jnp.float32))          # (H,), negative
+    x_hm = jnp.swapaxes(x, 1, 2)                     # (B, H, S, P)
+    dt_hm = jnp.swapaxes(dt, 1, 2)                   # (B, H, S)
 
     grid = (bsz, h, nc)
-    return pl.pallas_call(
+    y_hm = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p),
-                         lambda bi, hi, ci: (bi, ci, hi, 0)),     # x
-            pl.BlockSpec((1, chunk, 1),
-                         lambda bi, hi, ci: (bi, ci, hi)),        # dt
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),         # A
+            pl.BlockSpec((1, 1, chunk, p),
+                         lambda bi, hi, ci: (bi, hi, ci, 0)),     # x
+            pl.BlockSpec((1, 1, chunk, 1),
+                         lambda bi, hi, ci: (bi, hi, ci, 0)),     # dt col
+            pl.BlockSpec((1, 1, 1, chunk),
+                         lambda bi, hi, ci: (bi, hi, 0, ci)),     # dt row
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # A
             pl.BlockSpec((1, chunk, n),
                          lambda bi, hi, ci: (bi, ci, 0)),         # B
             pl.BlockSpec((1, chunk, n),
                          lambda bi, hi, ci: (bi, ci, 0)),         # C
         ],
-        out_specs=pl.BlockSpec((1, chunk, 1, p),
-                               lambda bi, hi, ci: (bi, ci, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, s, h, p), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, chunk, p),
+                               lambda bi, hi, ci: (bi, hi, ci, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, a, b, c)
+    )(x_hm, dt_hm[..., None], dt_hm[:, :, None, :], a, b, c)
+    return jnp.swapaxes(y_hm, 1, 2)

@@ -1,5 +1,2 @@
 # launch: mesh construction, multi-pod dry-run, HLO analysis, drivers.
-# NOTE: dryrun.py must be executed as a MAIN module (python -m
-# repro.launch.dryrun) so its XLA_FLAGS lines run before jax initializes;
-# do not import it from here.
 from . import mesh  # noqa: F401

@@ -1,29 +1,43 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the
-# device count on first init).  Everything else follows.
+"""Multi-pod dry run: lower and compile every (arch x shape) cell for the
+production mesh on placeholder CPU devices, and price it.
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import sys           # noqa: E402
-import time          # noqa: E402
-from typing import Dict, Optional, Tuple   # noqa: E402
+    PYTHONPATH=src python -m repro.launch.dryrun --arch h2o-danube-1.8b \
+        --shape decode_32k
 
-import jax           # noqa: E402
-import jax.numpy as jnp                    # noqa: E402
+Importing this module changes no JAX setting; ``main`` (or a caller, via
+``use_host_devices``) selects the 512 placeholder devices.
+"""
+import argparse
+import json
+import sys
+import time
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
 
 from ..configs import SHAPES, all_cells, cell_applicable, get_config, \
-    memory_len                              # noqa: E402
-from ..configs.base import ModelConfig      # noqa: E402
-from ..core import tpu as tpu_model          # noqa: E402
-from ..data import make_batch_specs          # noqa: E402
-from ..distributed import sharding           # noqa: E402
-from ..models import build                   # noqa: E402
-from ..optim.schedule import for_arch        # noqa: E402
-from ..train.serve_step import make_prefill, make_serve_step  # noqa: E402
-from ..train.train_step import init_state, make_train_step    # noqa: E402
-from . import hlo_analysis                   # noqa: E402
-from .mesh import make_production_mesh       # noqa: E402
+    memory_len
+from ..configs.base import ModelConfig
+from ..core import tpu as tpu_model
+from ..data import make_batch_specs
+from ..distributed import sharding
+from ..models import build
+from ..optim.schedule import for_arch
+from ..train.serve_step import make_prefill, make_serve_step
+from ..train.train_step import init_state, make_train_step
+from . import hlo_analysis
+from .mesh import make_production_mesh
+
+HOST_DEVICES = 512
+
+
+def use_host_devices() -> None:
+    """Run JAX on ``HOST_DEVICES`` placeholder CPU devices.  Call before
+    the first JAX operation of the process: backends read this once."""
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", HOST_DEVICES)
+
 
 # ---------------------------------------------------------------------------
 # Per-cell execution plans (baseline).  §Perf hillclimbing edits these.
@@ -383,6 +397,7 @@ def main(argv=None):
                     help="run every (arch x shape) cell")
     ap.add_argument("--json", default=None, help="append JSONL rows here")
     args = ap.parse_args(argv)
+    use_host_devices()
 
     cells: list
     if args.all:

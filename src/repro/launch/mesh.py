@@ -6,26 +6,34 @@ touches jax device state.  Production target: TPU v5e pods of 256 chips
 """
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with Auto axes: sharding follows the in/out
+    shardings and ``with_sharding_constraint`` hints, and ops such as
+    ``jnp.take`` need no explicit ``out_sharding``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    import math
     n = math.prod(shape)
     devs = jax.devices()
-    if len(devs) == n:
-        return jax.make_mesh(shape, axes)
     if len(devs) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}; have {len(devs)} — run "
-            f"via repro.launch.dryrun (sets "
-            f"xla_force_host_platform_device_count=512)")
+            f"via repro.launch.dryrun (its main selects 512 placeholder "
+            f"CPU devices)")
     # dry-run container: 512 placeholder devices; single-pod uses 256
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devices=devs[:n])
 
 
 def make_test_mesh(*, devices: Optional[int] = None, model: int = 2,
@@ -34,8 +42,8 @@ def make_test_mesh(*, devices: Optional[int] = None, model: int = 2,
     n = devices or len(jax.devices())
     data = n // (model * pod)
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_spec_of(mesh) -> "object":

@@ -7,17 +7,22 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..configs import get_config, memory_len
 from ..models import build
-from ..train.serve_step import greedy_generate
+from ..train.serve_step import Generation, greedy_generate
+from .compile_cache import enable_compile_cache
 
 
 def serve(arch: str, *, smoke: bool = True, batch: int = 4,
-          prompt_len: int = 32, max_new: int = 16, seed: int = 0):
+          prompt_len: int = 32, max_new: int = 16, seed: int = 0
+          ) -> Tuple[jax.Array, Generation]:
+    """Greedy-decode ``max_new`` tokens for a random prompt; returns the
+    prompt and the ``Generation``.  Weights and prompt come from ``seed``."""
     cfg = get_config(arch, smoke=smoke)
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(seed))
@@ -28,14 +33,15 @@ def serve(arch: str, *, smoke: bool = True, batch: int = 4,
     if mlen is not None:
         mem = jax.random.normal(key, (batch, max(mlen, 4), cfg.d_model),
                                 jnp.float32)
-    t0 = time.time()
+    t0 = time.perf_counter()
     out = greedy_generate(model, params, prompt, max_new=max_new,
                           memory_embeds=mem)
-    dt = time.time() - t0
+    jax.block_until_ready(out)
+    dt = time.perf_counter() - t0
     toks = batch * max_new
     print(f"[serve] {arch}: generated {toks} tokens in {dt:.2f}s "
-          f"({toks / dt:.1f} tok/s incl. prefill)")
-    return out
+          f"({toks / dt:.1f} tok/s incl. prefill and compilation)")
+    return prompt, out
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -54,6 +60,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     serve(args.arch, smoke=args.smoke, batch=args.batch,
           prompt_len=args.prompt_len, max_new=args.max_new)
 

@@ -24,6 +24,7 @@ from ..models import build
 from ..optim.schedule import for_arch
 from ..train import checkpoint as ckpt
 from ..train.train_step import init_state, make_train_step
+from .compile_cache import enable_compile_cache
 
 
 def train(arch: str, *, smoke: bool = True, steps: int = 50,
@@ -104,6 +105,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = train(args.arch, smoke=args.smoke, steps=args.steps,
                 batch=args.batch, seq=args.seq, lr=args.lr,
                 microbatches=args.microbatches,

@@ -24,7 +24,11 @@ from . import rglru as rglru_mod
 from . import ssm as ssm_mod
 from .layers import dtype_of, mlp_apply, mlp_init, rmsnorm
 
-ZERO = jnp.zeros((), jnp.float32)
+
+def _no_aux():
+    """Zero auxiliary loss (built per call: a module-level array would
+    start a JAX backend at import)."""
+    return jnp.zeros((), jnp.float32)
 
 
 def _norm_init(cfg):
@@ -52,7 +56,7 @@ def _attn_block_apply(p, x, cfg: ModelConfig, *, window=0, causal=True,
     if "mlp" in p:
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
         x = x + mlp_apply(p["mlp"], h, cfg)
-    return x, ZERO
+    return x, _no_aux()
 
 
 def _attn_cache(cfg, batch, max_len, *, window=0):
@@ -125,7 +129,7 @@ def _ssm_block_init(key, cfg: ModelConfig):
 
 def _ssm_block_apply(p, x, cfg: ModelConfig, *, memory=None):
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    return x + ssm_mod.ssm_apply(p["ssm"], h, cfg), ZERO
+    return x + ssm_mod.ssm_apply(p["ssm"], h, cfg), _no_aux()
 
 
 def _ssm_cache(cfg, batch, max_len, **kw):
@@ -157,7 +161,7 @@ def _rglru_block_apply(p, x, cfg: ModelConfig, *, memory=None):
     if "mlp" in p:
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
         x = x + mlp_apply(p["mlp"], h, cfg)
-    return x, ZERO
+    return x, _no_aux()
 
 
 def _rglru_cache(cfg, batch, max_len, **kw):
@@ -195,7 +199,7 @@ def _cross_block_apply(p, x, cfg: ModelConfig, *, memory=None):
                              kv_override=memory)
     x = x + jnp.tanh(p["xgate"]).astype(x.dtype) * xo
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    return x + mlp_apply(p["mlp"], h, cfg), ZERO
+    return x + mlp_apply(p["mlp"], h, cfg), _no_aux()
 
 
 def _cross_cache(cfg, batch, max_len, **kw):

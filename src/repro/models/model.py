@@ -334,9 +334,10 @@ class LanguageModel:
                 memory_embeds=memory_embeds)
             return (cache, logits), None
 
+        # the carry's logits take decode_step's dtype (the config's)
         (cache, logits), _ = jax.lax.scan(
             body, (cache, jnp.zeros((tokens.shape[0], self.cfg.vocab),
-                                    jnp.float32)),
+                                    dtype_of(self.cfg))),
             jnp.arange(s))
         return logits, cache
 

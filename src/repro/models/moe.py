@@ -237,8 +237,7 @@ def moe_apply_sharded(p, x, cfg: ModelConfig, *, mesh, dp_axes,
     dp = tuple(dp_axes) if dp_axes else None
     body = functools.partial(_moe_dispatch_local, cap_local=cap_local,
                              model_axis=model_axis, dt=dt)
-    from ..distributed.sharding import shard_map
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, None), P(dp, None), P(dp, None),

@@ -95,7 +95,7 @@ def ssm_apply(p, x, cfg: ModelConfig):
     from ..distributed import sharding as shd
     from ..distributed.sharding import axis_size
     from ..kernels.ssd import ssd_scan
-    mesh = shd._ACTIVE_MESH.get()
+    mesh = shd.active_mesh()
     if cfg.ssd_shard_map and mesh is not None and axis_size("model") > 1:
         rules = shd.current_rules() or {}
         dp = rules.get("batch")
@@ -192,14 +192,12 @@ def ssd_apply_shard_map(xh, dt, a_log, bmat, cmat, cfg: ModelConfig, *,
     import functools
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.sharding import shard_map
-
     dp = tuple(dp_axes) if dp_axes else None
     body = functools.partial(
         _ssd_local_body, chunk=cfg.ssm_chunk,
         unroll_heads=cfg.attn_chunk_unroll,
         tile_dtype=jnp.bfloat16 if cfg.ssd_tile_bf16 else None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, None, model_axis, None),   # x heads sharded

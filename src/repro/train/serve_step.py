@@ -3,11 +3,12 @@ decode_32k / long_500k dry-run cells lower), plus a simple batched
 request loop for the serving example."""
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..distributed import sharding
 from ..models import LanguageModel
 
 
@@ -37,23 +38,54 @@ def make_serve_step(model: LanguageModel) -> Callable:
     return serve_step
 
 
+class Generation(NamedTuple):
+    tokens: jax.Array     # (B, max_new) int32
+    logits: jax.Array     # (B, max_new, V); tokens[:, i] = argmax logits[:, i]
+    compiles_after_first_step: int   # lowerings once the decode step ran
+
+
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
 def greedy_generate(model: LanguageModel, params, prompt, *, max_new: int,
-                    max_len: Optional[int] = None, memory_embeds=None):
-    """Batched greedy decoding driver (example/serving path)."""
+                    max_len: Optional[int] = None,
+                    memory_embeds=None) -> Generation:
+    """Batched greedy decoding driver (example/serving path).
+
+    Under ``sharding.use_mesh`` the cache is placed by
+    ``cache_specs_tree``.  Every jitted lowering after the first decode
+    step is counted: a steady decode loop compiles nothing."""
     b, s = prompt.shape
     max_len = max_len or (s + max_new)
     cache = model.init_cache(b, max_len)
+    mesh = sharding.active_mesh()
+    if mesh is not None:
+        cache = jax.device_put(cache, sharding.tree_shardings(
+            mesh, sharding.cache_specs_tree(cache, mesh=mesh)))
     # prefill fills the cache through position s-1 and returns the
     # last-token logits
     logits, cache = model.prefill(params, prompt, cache,
                                   memory_embeds=memory_embeds)
     step = jax.jit(model.decode_step)
 
-    toks = []
-    for i in range(max_new):
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        toks.append(nxt)
-        if i + 1 < max_new:
-            logits, cache = step(params, cache, nxt, jnp.int32(s + i),
-                                 memory_embeds=memory_embeds)
-    return jnp.concatenate(toks, axis=1)
+    lowerings, armed = [], [False]
+
+    def on_event(event, _secs, **_kw):
+        if armed[0] and event == _LOWERING_EVENT:
+            lowerings.append(event)
+
+    toks, outs = [], []
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for i in range(max_new):
+            armed[0] = i > 0    # the first decode step has compiled
+            outs.append(logits)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            toks.append(nxt)
+            if i + 1 < max_new:
+                logits, cache = step(params, cache, nxt, jnp.int32(s + i),
+                                     memory_embeds=memory_embeds)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    return Generation(jnp.concatenate(toks, axis=1), jnp.stack(outs, axis=1),
+                      len(lowerings))

@@ -24,6 +24,7 @@ def run_sub(body: str, devices: int = 8) -> str:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ,
                PYTHONPATH=os.path.join(REPO, "src"))
@@ -38,7 +39,7 @@ class TestShardingRules:
         out = run_sub("""
             from jax.sharding import PartitionSpec as P
             from repro.distributed import sharding
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             params = {
                 "tok_embed": jnp.zeros((128, 64)),
                 "lm_head": jnp.zeros((64, 128)),
@@ -64,7 +65,7 @@ class TestShardingRules:
         out = run_sub("""
             from jax.sharding import PartitionSpec as P
             from repro.distributed import sharding
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             # vocab 127 is prime: model axis (2) cannot shard it
             specs = sharding.param_specs(
                 {"tok_embed": jnp.zeros((127, 64))}, mesh=mesh)
@@ -104,7 +105,7 @@ class TestSmallMeshCompile:
             cfg = ModelConfig(name="t", family="dense", n_layers=2,
                               d_model=64, n_heads=4, n_kv_heads=2,
                               d_ff=128, vocab=256)
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
             model = build(cfg)
             with sharding.use_mesh(mesh, {}):
                 state = jax.eval_shape(
@@ -141,7 +142,7 @@ class TestSmallMeshCompile:
             cfg = ModelConfig(name="t", family="dense", n_layers=2,
                               d_model=64, n_heads=4, n_kv_heads=2,
                               d_ff=128, vocab=256)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             model = build(cfg)
             with sharding.use_mesh(mesh, {}):
                 params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -185,7 +186,7 @@ class TestSmallMeshCompile:
             step = make_train_step(model, lr=1e-3)
             _, m_single = jax.jit(step)(state, batch)
 
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             with sharding.use_mesh(mesh, {}):
                 st_sh = sharding.tree_shardings(
                     mesh, sharding.param_specs(state, mesh=mesh))
@@ -209,14 +210,14 @@ class TestSmallMeshCompile:
             from repro.train import checkpoint as ckpt
 
             tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-            mesh8 = jax.make_mesh((8,), ("data",))
+            mesh8 = make_mesh((8,), ("data",))
             sh8 = {"w": NamedSharding(mesh8, P("data", None))}
             tree8 = jax.device_put(tree, sh8)
             d = tempfile.mkdtemp()
             path = d + "/ckpt_000001"
             ckpt.save(path, tree8, step=1)
 
-            mesh4 = jax.make_mesh((4,), ("data",),
+            mesh4 = make_mesh((4,), ("data",),
                                   devices=jax.devices()[:4])
             sh4 = {"w": NamedSharding(mesh4, P("data", None))}
             restored, man = ckpt.restore(path, tree, shardings=sh4)

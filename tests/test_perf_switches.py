@@ -42,6 +42,7 @@ def test_ssd_shard_map_matches_gspmd():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
         from repro.distributed import sharding
+        from repro.launch.mesh import make_mesh
         from repro.models import build
 
         cfg = get_config("mamba2-1.3b", smoke=True).replace(
@@ -51,7 +52,7 @@ def test_ssd_shard_map_matches_gspmd():
         params = model0.init(jax.random.PRNGKey(0))
         toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
                                   cfg.vocab)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with sharding.use_mesh(mesh, {}):
             l0 = jax.jit(lambda p, t: model0.forward(p, t)[0])(params, toks)
             l1 = jax.jit(lambda p, t: model1.forward(p, t)[0])(params, toks)

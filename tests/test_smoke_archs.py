@@ -71,6 +71,24 @@ class TestSmoke:
         err = float(jnp.max(jnp.abs(last - logits[:, -1, :])))
         assert err < 5e-3, err
 
+    def test_bf16_prefill_matches_forward(self, arch):
+        """Every full config computes in bfloat16: prefill's scan must
+        carry bf16 logits and agree with the forward pass."""
+        cfg = get_config(arch, smoke=True).replace(dtype="bfloat16",
+                                                   param_dtype="bfloat16")
+        model = build(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        batch = _batch(cfg, jax.random.PRNGKey(1))
+        logits, _ = model.forward(params, batch["tokens"],
+                                  memory_embeds=batch.get("memory_embeds"))
+        last, _ = model.prefill(params, batch["tokens"],
+                                model.init_cache(BATCH, SEQ),
+                                memory_embeds=batch.get("memory_embeds"))
+        assert last.dtype == jnp.bfloat16
+        ref = logits[:, -1, :].astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(last.astype(jnp.float32) - ref)))
+        assert err <= 5e-2 * float(jnp.max(jnp.abs(ref))), err
+
     def test_full_config_bookkeeping(self, arch):
         """Full config: analytic param count sane, exact assigned dims."""
         cfg = get_config(arch)

@@ -232,9 +232,32 @@ class TestEndToEnd:
         model = build(TINY)
         params = model.init(jax.random.PRNGKey(0))
         prompt = jnp.ones((2, 8), jnp.int32)
-        out = greedy_generate(model, params, prompt, max_new=5)
+        gen = greedy_generate(model, params, prompt, max_new=5)
+        out = gen.tokens
         assert out.shape == (2, 5)
         assert bool(jnp.all(out >= 0)) and bool(jnp.all(out < TINY.vocab))
+        assert gen.logits.shape == (2, 5, TINY.vocab)
+        assert bool(jnp.all(out == jnp.argmax(gen.logits, axis=-1)))
+        assert gen.compiles_after_first_step == 0
+
+    def test_greedy_generate_counts_compiles_in_the_loop(self):
+        """A prefill whose logits dtype differs from the decode step's makes
+        the loop compile a second argmax: the counter must see it.  (A
+        vocab no other test uses, so that argmax is not compiled yet.)"""
+        model = build(TINY.replace(vocab=97))
+
+        class Bf16Prefill:
+            init_cache = model.init_cache
+            decode_step = model.decode_step
+
+            def prefill(self, *a, **kw):
+                logits, cache = model.prefill(*a, **kw)
+                return logits.astype(jnp.bfloat16), cache
+
+        params = model.init(jax.random.PRNGKey(0))
+        gen = greedy_generate(Bf16Prefill(), params,
+                              jnp.ones((2, 8), jnp.int32), max_new=4)
+        assert gen.compiles_after_first_step > 0
 
     def test_train_resume_from_checkpoint_exact(self, tmp_path):
         """Train 5 steps, checkpoint, train 5 more; vs. train 10 straight:
@@ -262,3 +285,48 @@ class TestEndToEnd:
                         jax.tree.leaves(s_resumed["params"])):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-6)
+
+
+class TestCompileCache:
+    """launch/compile_cache.py: the environment's directory when set,
+    else a fixed path inside the checkout."""
+
+    def _run(self, tmp_path, env_dir):
+        import subprocess
+        import sys
+        import textwrap
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = textwrap.dedent("""
+            import jax, jax.numpy as jnp
+            from repro.launch.compile_cache import enable_compile_cache
+            used = enable_compile_cache()
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+            print(used)
+            print(jax.config.jax_compilation_cache_dir)
+        """ if env_dir else """
+            import jax
+            from repro.launch.compile_cache import enable_compile_cache
+            print(enable_compile_cache())
+            print(jax.config.jax_compilation_cache_dir)
+        """)
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(PYTHONPATH=os.path.join(repo, "src"), JAX_PLATFORMS="cpu")
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             cwd=tmp_path, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr
+        return repo, out.stdout.split()
+
+    def test_environment_directory_wins(self, tmp_path):
+        want = str(tmp_path / "cc")
+        _, (used, config) = self._run(tmp_path, want)
+        assert used == config == want
+        assert os.listdir(want)          # entries written there
+
+    def test_default_is_a_fixed_path_in_the_checkout(self, tmp_path):
+        repo, (used, config) = self._run(tmp_path, None)
+        assert used == config == os.path.join(repo, ".jax_cache")
