@@ -2,7 +2,7 @@
 
 The exposition contract (serve/README.md, enforced at runtime by
 ``tests/test_obs.py``): every family matches
-``repro_{serve,client,sweep,pool}_*``, label keys come from a small
+``repro_{serve,client,sweep,pool,jax}_*``, label keys come from a small
 closed vocabulary, and the family inventory is append-only —
 dashboards and scrapers bind to these names, so a silent rename is a
 breaking API change that no unit test of the renamed code will catch.
@@ -13,7 +13,7 @@ Per file, the rule checks every ``counter(...)`` / ``gauge(...)`` /
 * the family (first argument) must be a **string literal** — a computed
   name cannot be checked against the contract, and the registry's
   append-only test can't see it either;
-* the literal must match ``repro_(serve|client|sweep|pool)_[a-z0-9_]+``;
+* the literal must match ``repro_(serve|client|sweep|pool|jax)_[a-z0-9_]+``;
 * every label kwarg key must be in the closed label vocabulary, and a
   *literal* label value must be in that key's closed value set.
 
@@ -35,7 +35,7 @@ from typing import Dict, Iterable, List, Tuple
 from ..astutil import attr_chain, const_value
 from ..core import Finding, Module, Project, Rule, register
 
-FAMILY_RE = re.compile(r"^repro_(serve|client|sweep|pool)_[a-z0-9_]+$")
+FAMILY_RE = re.compile(r"^repro_(serve|client|sweep|pool|jax)_[a-z0-9_]+$")
 
 #: closed label vocabulary: key -> allowed literal values
 LABEL_VALUES: Dict[str, frozenset] = {
@@ -44,6 +44,8 @@ LABEL_VALUES: Dict[str, frozenset] = {
                         "encode", "write"}),
     "reason": frozenset({"overload", "deadline"}),
     "cache": frozenset({"hit", "miss"}),
+    "result": frozenset({"hit", "miss"}),
+    "phase": frozenset({"lowering", "backend", "cache_load"}),
 }
 
 #: kwargs of the registration helpers that are not labels
@@ -59,10 +61,11 @@ CONTRACT_TEST_REL = "tests/test_obs.py"
 @register
 class MetricNameRule(Rule):
     id = "METRIC-NAME"
-    hint = ("metric families follow repro_{serve,client,sweep,pool}_* "
+    hint = ("metric families follow repro_{serve,client,sweep,pool,jax}_* "
             "with label keys from the closed vocabulary "
-            "(transport/stage/reason/cache); the family inventory is "
-            "append-only — see tests/test_obs.py EXPECTED_FAMILIES")
+            "(transport/stage/reason/cache/result/phase); the family "
+            "inventory is append-only — see tests/test_obs.py "
+            "EXPECTED_FAMILIES")
 
     def __init__(self):
         #: family -> first registration site, for the finalize check
@@ -93,7 +96,7 @@ class MetricNameRule(Rule):
                 out.append(self.finding(
                     module.rel, call.lineno,
                     f"metric family {family!r} is outside the "
-                    f"repro_{{serve,client,sweep,pool}}_* namespace"))
+                    f"repro_{{serve,client,sweep,pool,jax}}_* namespace"))
             else:
                 self.declared.setdefault(
                     family, (module.rel, call.lineno))

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
-from .layers import apply_rope, dense_init, dtype_of, pdtype_of, softcap
+from .layers import (apply_rope, dense_init, dtype_of, pdtype_of, scope,
+                     softcap)
 
 NEG_INF = -1e30
 
@@ -171,8 +172,11 @@ def decode_attn_apply(p, x, cache: Dict, pos, cfg: ModelConfig, *,
 
     size = cache["k"].shape[1]
     slot = pos % size if window > 0 else pos
-    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
+    with scope("cache"):
+        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot,
+                                                axis=1)
+        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot,
+                                                axis=1)
 
     hkv = cfg.n_kv_heads
     group = cfg.n_heads // hkv

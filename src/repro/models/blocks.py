@@ -1,6 +1,7 @@
 """Block registry: per-kind (init, apply, cache_init/spec, decode).
 
-Every block owns its norms and residual adds.  Kinds:
+Every block owns its norms and residual adds, each under the scope of
+the block part it feeds (``layers.SCOPES``).  Kinds:
   attn        full causal GQA attention + SwiGLU MLP
   local_attn  sliding-window GQA attention + MLP
   moe         (MLA or GQA) attention + MoE FFN (returns aux loss)
@@ -22,7 +23,7 @@ from . import mla as mla_mod
 from . import moe as moe_mod
 from . import rglru as rglru_mod
 from . import ssm as ssm_mod
-from .layers import dtype_of, mlp_apply, mlp_init, rmsnorm
+from .layers import dtype_of, mlp_apply, mlp_init, rmsnorm, scope
 
 
 def _no_aux():
@@ -33,6 +34,13 @@ def _no_aux():
 
 def _norm_init(cfg):
     return jnp.ones((cfg.d_model,), jnp.dtype(cfg.param_dtype))
+
+
+@scope("mlp")
+def _mlp_residual(p, x, cfg: ModelConfig):
+    """x + MLP(norm(x))."""
+    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+    return x + mlp_apply(p["mlp"], h, cfg)
 
 
 # --------------------------------------------------------------------------
@@ -50,12 +58,12 @@ def _attn_block_init(key, cfg: ModelConfig):
 
 def _attn_block_apply(p, x, cfg: ModelConfig, *, window=0, causal=True,
                       memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=causal,
-                                window=window)
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=causal,
+                                    window=window)
     if "mlp" in p:
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        x = x + mlp_apply(p["mlp"], h, cfg)
+        x = _mlp_residual(p, x, cfg)
     return x, _no_aux()
 
 
@@ -65,13 +73,13 @@ def _attn_cache(cfg, batch, max_len, *, window=0):
 
 def _attn_decode(p, x, cache, pos, cfg: ModelConfig, *, window=0,
                  memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    o, kv = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"], pos, cfg,
-                                       window=window)
-    x = x + o
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        o, kv = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"], pos,
+                                           cfg, window=window)
+        x = x + o
     if "mlp" in p:
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        x = x + mlp_apply(p["mlp"], h, cfg)
+        x = _mlp_residual(p, x, cfg)
     return x, {"kv": kv}
 
 
@@ -88,14 +96,16 @@ def _moe_block_init(key, cfg: ModelConfig):
 
 
 def _moe_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if cfg.use_mla:
-        x = x + mla_mod.mla_apply(p["attn"], h, cfg)
-    else:
-        x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    y, aux = moe_mod.moe_apply(p["moe"], h, cfg)
-    return x + y, aux
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if cfg.use_mla:
+            x = x + mla_mod.mla_apply(p["attn"], h, cfg)
+        else:
+            x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
+    with scope("moe"):
+        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        y, aux = moe_mod.moe_apply(p["moe"], h, cfg)
+        return x + y, aux
 
 
 def _moe_cache(cfg, batch, max_len, **kw):
@@ -105,18 +115,21 @@ def _moe_cache(cfg, batch, max_len, **kw):
 
 
 def _moe_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if cfg.use_mla:
-        o, new = mla_mod.mla_decode(p["attn"], h, cache["mla"], pos, cfg)
-        new_cache = {"mla": new}
-    else:
-        o, new = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"],
-                                            pos, cfg)
-        new_cache = {"kv": new}
-    x = x + o
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    y, _ = moe_mod.moe_apply(p["moe"], h, cfg)
-    return x + y, new_cache
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if cfg.use_mla:
+            o, new = mla_mod.mla_decode(p["attn"], h, cache["mla"], pos,
+                                        cfg)
+            new_cache = {"mla": new}
+        else:
+            o, new = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"],
+                                                pos, cfg)
+            new_cache = {"kv": new}
+        x = x + o
+    with scope("moe"):
+        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        y, _ = moe_mod.moe_apply(p["moe"], h, cfg)
+        return x + y, new_cache
 
 
 # --------------------------------------------------------------------------
@@ -127,6 +140,7 @@ def _ssm_block_init(key, cfg: ModelConfig):
     return {"ln1": _norm_init(cfg), "ssm": ssm_mod.ssm_init(key, cfg)}
 
 
+@scope("ssm")
 def _ssm_block_apply(p, x, cfg: ModelConfig, *, memory=None):
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     return x + ssm_mod.ssm_apply(p["ssm"], h, cfg), _no_aux()
@@ -136,6 +150,7 @@ def _ssm_cache(cfg, batch, max_len, **kw):
     return {"ssm": ssm_mod.init_ssm_cache(cfg, batch)}
 
 
+@scope("ssm")
 def _ssm_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     o, new = ssm_mod.ssm_decode(p["ssm"], h, cache["ssm"], pos, cfg)
@@ -156,11 +171,11 @@ def _rglru_block_init(key, cfg: ModelConfig):
 
 
 def _rglru_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    x = x + rglru_mod.rglru_apply(p["lru"], h, cfg)
+    with scope("rglru"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        x = x + rglru_mod.rglru_apply(p["lru"], h, cfg)
     if "mlp" in p:
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        x = x + mlp_apply(p["mlp"], h, cfg)
+        x = _mlp_residual(p, x, cfg)
     return x, _no_aux()
 
 
@@ -169,12 +184,12 @@ def _rglru_cache(cfg, batch, max_len, **kw):
 
 
 def _rglru_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    o, new = rglru_mod.rglru_decode(p["lru"], h, cache["lru"], pos, cfg)
-    x = x + o
+    with scope("rglru"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        o, new = rglru_mod.rglru_decode(p["lru"], h, cache["lru"], pos, cfg)
+        x = x + o
     if "mlp" in p:
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        x = x + mlp_apply(p["mlp"], h, cfg)
+        x = _mlp_residual(p, x, cfg)
     return x, {"lru": new}
 
 
@@ -190,16 +205,22 @@ def _cross_block_init(key, cfg: ModelConfig):
             "xgate": jnp.zeros((), jnp.dtype(cfg.param_dtype))}
 
 
-def _cross_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    assert memory is not None, "cross_attn block needs memory"
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
+@scope("cross")
+def _cross_residual(p, x, memory, cfg: ModelConfig):
+    """x + gate * cross-attention(norm(x), memory)."""
     h = rmsnorm(x, p["lnx"], cfg.norm_eps)
     xo = attn_mod.attn_apply(p["xattn"], h, cfg, causal=False,
                              kv_override=memory)
-    x = x + jnp.tanh(p["xgate"]).astype(x.dtype) * xo
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    return x + mlp_apply(p["mlp"], h, cfg), _no_aux()
+    return x + jnp.tanh(p["xgate"]).astype(x.dtype) * xo
+
+
+def _cross_block_apply(p, x, cfg: ModelConfig, *, memory=None):
+    assert memory is not None, "cross_attn block needs memory"
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
+    x = _cross_residual(p, x, memory, cfg)
+    return _mlp_residual(p, x, cfg), _no_aux()
 
 
 def _cross_cache(cfg, batch, max_len, **kw):
@@ -207,15 +228,13 @@ def _cross_cache(cfg, batch, max_len, **kw):
 
 
 def _cross_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    o, kv = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"], pos, cfg)
-    x = x + o
-    h = rmsnorm(x, p["lnx"], cfg.norm_eps)
-    xo = attn_mod.attn_apply(p["xattn"], h, cfg, causal=False,
-                             kv_override=memory)
-    x = x + jnp.tanh(p["xgate"]).astype(x.dtype) * xo
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    return x + mlp_apply(p["mlp"], h, cfg), {"kv": kv}
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        o, kv = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"], pos,
+                                           cfg)
+        x = x + o
+    x = _cross_residual(p, x, memory, cfg)
+    return _mlp_residual(p, x, cfg), {"kv": kv}
 
 
 def _enc_block_apply(p, x, cfg: ModelConfig, *, memory=None):

@@ -10,6 +10,27 @@ from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
 
 
+#: The names of the ``jax.named_scope`` the model puts on its device ops,
+#: and the only ones it uses (``scope`` refuses any other).  An op takes
+#: the innermost of them on its path: ``embed``; ``layers``, the loop over
+#: layer groups (with its slicing of the stacked weights and cache); one
+#: per block part, each with its norm and residual add (``attn``, ``mlp``,
+#: ``ssm``, ``moe``, ``rglru``, ``cross``); ``cache``, the ops that write
+#: a block's carried decode state; ``head``, the final norm and logits.
+#: The chip benchmark's per-layer device times read this list, so a name
+#: changed here changes what they measure.
+SCOPES = ("embed", "layers", "attn", "mlp", "ssm", "moe", "rglru", "cross",
+          "cache", "head")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of ``SCOPES``: metadata on the
+    ops traced inside it, with no effect on what they compute."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of {SCOPES}")
+    return jax.named_scope(name)
+
+
 def dtype_of(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
 from .attention import NEG_INF
-from .layers import apply_rope, dense_init, dtype_of, pdtype_of
+from .layers import apply_rope, dense_init, dtype_of, pdtype_of, scope
 
 
 def mla_init(key, cfg: ModelConfig):
@@ -105,10 +105,11 @@ def mla_decode(p, x, cache: Dict, pos, cfg: ModelConfig
     posv = jnp.full((b, 1), pos)
     q_nope, q_rope = _queries(p, x, cfg, posv)
     lat_new, kr_new = _latent_kv(p, x, cfg, posv)
-    latent = jax.lax.dynamic_update_slice_in_dim(
-        cache["latent"], lat_new, pos, axis=1)
-    k_rope = jax.lax.dynamic_update_slice_in_dim(
-        cache["k_rope"], kr_new, pos, axis=1)
+    with scope("cache"):
+        latent = jax.lax.dynamic_update_slice_in_dim(
+            cache["latent"], lat_new, pos, axis=1)
+        k_rope = jax.lax.dynamic_update_slice_in_dim(
+            cache["k_rope"], kr_new, pos, axis=1)
     valid = jnp.arange(latent.shape[1]) <= pos
     o = _attend(q_nope, q_rope, latent, k_rope, p, cfg, causal=False,
                 valid=valid)

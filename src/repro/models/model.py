@@ -3,7 +3,7 @@ modality memory), with train forward, loss, prefill and one-token decode.
 
 Layers are stored STACKED (leading dim = n_groups) and executed with
 jax.lax.scan so compile time is independent of depth; remat policy wraps
-the per-group apply.
+the per-group apply.  Device ops carry the names of ``layers.SCOPES``.
 """
 from __future__ import annotations
 
@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
 from .blocks import REGISTRY
-from .layers import dtype_of, embed_init, pdtype_of, rmsnorm
+from .layers import dtype_of, embed_init, pdtype_of, rmsnorm, scope
 
 
 class LanguageModel:
@@ -72,6 +72,7 @@ class LanguageModel:
         return params
 
     # -------------------------------------------------------------- forward
+    @scope("embed")
     def _embed(self, params, tokens):
         cfg = self.cfg
         x = jnp.take(params["tok_embed"], tokens, axis=0).astype(
@@ -85,6 +86,12 @@ class LanguageModel:
         logits = (x @ head) * cfg.logit_scale
         return constrain(logits, ("batch", "seq", "vocab"))
 
+    @scope("head")
+    def _head(self, params, x):
+        """Final norm and logits."""
+        x = rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
+        return self._logits(params, x)
+
     def _group_apply(self, gparams, x, memory):
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
@@ -94,6 +101,7 @@ class LanguageModel:
             aux = aux + a
         return x, aux
 
+    @scope("layers")
     def _run_groups(self, params, x, memory):
         cfg = self.cfg
         apply = self._group_apply
@@ -118,6 +126,7 @@ class LanguageModel:
                 aux = aux + a
         return x, aux
 
+    @scope("layers")
     def _run_prefix(self, params, x):
         cfg = self.cfg
         if cfg.first_dense == 0:
@@ -139,16 +148,17 @@ class LanguageModel:
         cfg = self.cfg
         x = frame_embeds.astype(dtype_of(cfg))
 
-        if cfg.scan_layers:
-            def body(h, bparams):
-                h, _ = REGISTRY["enc_attn"].apply(bparams, h, cfg)
-                return h, None
-            x, _ = jax.lax.scan(body, x, params["encoder"]["blocks"])
-        else:
-            for i in range(cfg.enc_layers):
-                bp = jax.tree.map(lambda a: a[i],
-                                  params["encoder"]["blocks"])
-                x, _ = REGISTRY["enc_attn"].apply(bp, x, cfg)
+        with scope("layers"):
+            if cfg.scan_layers:
+                def body(h, bparams):
+                    h, _ = REGISTRY["enc_attn"].apply(bparams, h, cfg)
+                    return h, None
+                x, _ = jax.lax.scan(body, x, params["encoder"]["blocks"])
+            else:
+                for i in range(cfg.enc_layers):
+                    bp = jax.tree.map(lambda a: a[i],
+                                      params["encoder"]["blocks"])
+                    x, _ = REGISTRY["enc_attn"].apply(bp, x, cfg)
         return rmsnorm(x, params["encoder"]["final_norm"], cfg.norm_eps)
 
     def forward(self, params, tokens, *, memory_embeds=None
@@ -170,8 +180,7 @@ class LanguageModel:
         x = self._embed(params, tokens)
         x = self._run_prefix(params, x)
         x, aux = self._run_groups(params, x, memory)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        return self._logits(params, x), aux
+        return self._head(params, x), aux
 
     # ----------------------------------------------------------------- loss
     def loss_fn(self, params, batch: Dict) -> Tuple[jax.Array, Dict]:
@@ -187,8 +196,7 @@ class LanguageModel:
             x = self._embed(params, batch["tokens"])
             x = self._run_prefix(params, x)
             trunk, aux = self._run_groups(params, x, memory)
-            logits = self._logits(
-                params, rmsnorm(trunk, params["final_norm"], cfg.norm_eps))
+            logits = self._head(params, trunk)
         else:
             logits, aux = self.forward(
                 params, batch["tokens"],
@@ -227,7 +235,8 @@ class LanguageModel:
             @ params["mtp"]["proj"].astype(dtype_of(cfg))
         fused, _ = REGISTRY["attn"].apply(params["mtp"]["block"], fused,
                                           cfg.replace(pattern=("attn",)))
-        logits = self._logits(params, fused)
+        with scope("head"):
+            logits = self._logits(params, fused)
         mtp_labels = jnp.roll(labels, -1, axis=1)
         valid = mtp_labels >= 0
         valid = valid.at[:, -2:].set(False)
@@ -284,18 +293,10 @@ class LanguageModel:
                 h, nc = REGISTRY["attn"].decode(bp, h, bc, pos, dense_cfg)
                 return h, nc
 
-            if cfg.scan_layers:
-                x, new_cache["prefix"] = jax.lax.scan(
-                    pre_body, x, (params["prefix"], cache["prefix"]))
-            else:
-                ncs = []
-                for i in range(cfg.first_dense):
-                    inp = jax.tree.map(lambda a: a[i],
-                                       (params["prefix"], cache["prefix"]))
-                    x, nc = pre_body(x, inp)
-                    ncs.append(nc)
-                new_cache["prefix"] = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *ncs)
+            with scope("layers"):
+                x, new_cache["prefix"] = self._decode_layers(
+                    pre_body, x, params["prefix"], cache["prefix"],
+                    cfg.first_dense)
 
         def body(h, inp):
             gp, gc = inp
@@ -306,21 +307,22 @@ class LanguageModel:
                 ncs[f"b{i}"] = nc
             return h, ncs
 
-        if cfg.scan_layers:
-            x, new_cache["groups"] = jax.lax.scan(
-                body, x, (params["groups"], cache["groups"]))
-        else:
-            ncs = []
-            for g in range(cfg.n_groups):
-                inp = jax.tree.map(lambda a: a[g],
-                                   (params["groups"], cache["groups"]))
-                x, nc = body(x, inp)
-                ncs.append(nc)
-            new_cache["groups"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *ncs)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        logits = self._logits(params, x)[:, 0, :]
+        with scope("layers"):
+            x, new_cache["groups"] = self._decode_layers(
+                body, x, params["groups"], cache["groups"], cfg.n_groups)
+        logits = self._head(params, x)[:, 0, :]
         return logits, new_cache
+
+    def _decode_layers(self, body, x, params, cache, n: int):
+        """``body`` over the stacked layers' params and cache: scanned, or
+        unrolled; returns x and the new stacked cache."""
+        if self.cfg.scan_layers:
+            return jax.lax.scan(body, x, (params, cache))
+        ncs = []
+        for i in range(n):
+            x, nc = body(x, jax.tree.map(lambda a: a[i], (params, cache)))
+            ncs.append(nc)
+        return x, jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
 
     def prefill(self, params, tokens, cache: Dict, *, memory_embeds=None):
         """Sequential prefill through decode_step (exactness over speed;
@@ -329,9 +331,10 @@ class LanguageModel:
 
         def body(carry, t):
             cache, last = carry
+            with scope("embed"):
+                token = tokens[:, t][:, None]
             logits, cache = self.decode_step(
-                params, cache, tokens[:, t][:, None], t,
-                memory_embeds=memory_embeds)
+                params, cache, token, t, memory_embeds=memory_embeds)
             return (cache, logits), None
 
         # the carry's logits take decode_step's dtype (the config's)

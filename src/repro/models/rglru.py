@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
-from .layers import dense_init, dtype_of, pdtype_of
+from .layers import dense_init, dtype_of, pdtype_of, scope
 
 RGLRU_C = 8.0
 N_GATE_BLOCKS = 8
@@ -107,12 +107,15 @@ def rglru_decode(p, x, cache: Dict, pos, cfg: ModelConfig
     dt = dtype_of(cfg)
     xb = (x @ p["wx"].astype(dt))[:, 0, :]         # (B, W)
     gate = jax.nn.gelu(x @ p["wy"].astype(dt))[:, 0, :]
-    hist = jnp.concatenate([cache["conv"], xb[:, None, :]], axis=1)
+    with scope("cache"):
+        hist = jnp.concatenate([cache["conv"], xb[:, None, :]], axis=1)
+        new_conv = hist[:, 1:, :]
     conv = jnp.einsum("bwc,wc->bc", hist, p["conv_w"].astype(dt)) \
         + p["conv_b"].astype(dt)
     r, i = _gates(p, conv)
     a = _decay(p, r)
     beta = jnp.sqrt(jnp.maximum(1.0 - a * a, 1e-12))
-    h = a * cache["h"] + beta * i * conv.astype(jnp.float32)
+    with scope("cache"):
+        h = a * cache["h"] + beta * i * conv.astype(jnp.float32)
     out = ((h.astype(dt) * gate) @ p["w_out"].astype(dt))[:, None, :]
-    return out, {"h": h, "conv": hist[:, 1:, :]}
+    return out, {"h": h, "conv": new_conv}

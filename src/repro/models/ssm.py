@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
-from .layers import dense_init, dtype_of, pdtype_of, rmsnorm
+from .layers import dense_init, dtype_of, pdtype_of, rmsnorm, scope
 
 
 def _dims(cfg: ModelConfig):
@@ -143,7 +143,9 @@ def ssm_decode(p, x, cache: Dict, pos, cfg: ModelConfig
                            x0 @ p["w_c"].astype(dt_)], axis=-1)
     dt_raw = x0 @ p["w_dtp"].astype(dt_)
 
-    hist = jnp.concatenate([cache["conv"], new[:, None, :]], axis=1)
+    with scope("cache"):
+        hist = jnp.concatenate([cache["conv"], new[:, None, :]], axis=1)
+        conv = hist[:, 1:, :]
     w = p["conv_w"].astype(dt_)
     conv_out = jnp.einsum("bwc,wc->bc", hist, w) + p["conv_b"].astype(dt_)
     xbc = jax.nn.silu(conv_out)
@@ -155,13 +157,14 @@ def ssm_decode(p, x, cache: Dict, pos, cfg: ModelConfig
     a = -jnp.exp(p["a_log"])                                  # (H,)
     da = jnp.exp(dt * a[None, :])                             # (B, H)
     inc = dt[:, :, None, None] * bmat[:, None, :, None] * xs[:, :, None, :]
-    ssm = da[:, :, None, None] * cache["ssm"] + inc           # (B,H,N,P)
+    with scope("cache"):
+        ssm = da[:, :, None, None] * cache["ssm"] + inc       # (B,H,N,P)
     y = jnp.einsum("bn,bhnp->bhp", cmat, ssm)
     y = y + xs * p["d_skip"][None, :, None]
     y = y.reshape(b, di).astype(dt_)
     y = rmsnorm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
     out = (y @ p["w_out"].astype(dt_))[:, None, :]
-    return out, {"conv": hist[:, 1:, :], "ssm": ssm}
+    return out, {"conv": conv, "ssm": ssm}
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,9 @@ import jax
 import jax.numpy as jnp
 
 from ..distributed import sharding
+from ..launch import compile_cache
 from ..models import LanguageModel
+from ..obs import metrics
 
 
 def make_prefill(model: LanguageModel) -> Callable:
@@ -41,10 +43,8 @@ def make_serve_step(model: LanguageModel) -> Callable:
 class Generation(NamedTuple):
     tokens: jax.Array     # (B, max_new) int32
     logits: jax.Array     # (B, max_new, V); tokens[:, i] = argmax logits[:, i]
-    compiles_after_first_step: int   # lowerings once the decode step ran
-
-
-_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    # lowerings once the decode step ran; None with the metrics switched off
+    compiles_after_first_step: Optional[int]
 
 
 def greedy_generate(model: LanguageModel, params, prompt, *, max_new: int,
@@ -54,7 +54,8 @@ def greedy_generate(model: LanguageModel, params, prompt, *, max_new: int,
 
     Under ``sharding.use_mesh`` the cache is placed by
     ``cache_specs_tree``.  Every jitted lowering after the first decode
-    step is counted: a steady decode loop compiles nothing."""
+    step is counted (``repro_jax_lowerings_total``): a steady decode loop
+    compiles nothing."""
     b, s = prompt.shape
     max_len = max_len or (s + max_new)
     cache = model.init_cache(b, max_len)
@@ -67,25 +68,19 @@ def greedy_generate(model: LanguageModel, params, prompt, *, max_new: int,
     logits, cache = model.prefill(params, prompt, cache,
                                   memory_embeds=memory_embeds)
     step = jax.jit(model.decode_step)
+    compile_cache.count_compiles()
 
-    lowerings, armed = [], [False]
-
-    def on_event(event, _secs, **_kw):
-        if armed[0] and event == _LOWERING_EVENT:
-            lowerings.append(event)
-
-    toks, outs = [], []
-    jax.monitoring.register_event_duration_secs_listener(on_event)
-    try:
-        for i in range(max_new):
-            armed[0] = i > 0    # the first decode step has compiled
-            outs.append(logits)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-            toks.append(nxt)
-            if i + 1 < max_new:
-                logits, cache = step(params, cache, nxt, jnp.int32(s + i),
-                                     memory_embeds=memory_embeds)
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on_event)
+    toks, outs, before = [], [], None
+    for i in range(max_new):
+        if i == 1:      # the first decode step has compiled
+            before = compile_cache.lowerings()
+        outs.append(logits)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        toks.append(nxt)
+        if i + 1 < max_new:
+            logits, cache = step(params, cache, nxt, jnp.int32(s + i),
+                                 memory_embeds=memory_embeds)
+    compiles = (None if not metrics.enabled() else 0 if before is None
+                else int(compile_cache.lowerings() - before))
     return Generation(jnp.concatenate(toks, axis=1), jnp.stack(outs, axis=1),
-                      len(lowerings))
+                      compiles)

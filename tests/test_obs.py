@@ -16,11 +16,13 @@ Three layers are pinned here:
 """
 import json
 import threading
+import time
 
 import pytest
 
 from repro.core import hardware, sweep
 from repro.core.workload import TileConfig, WorkloadTable, gemm_workload
+from repro.launch import compile_cache
 from repro.obs import metrics, trace
 from repro.serve.client import PredictionClient
 from repro.serve.server import Coalescer, PredictionServer
@@ -59,8 +61,20 @@ def exemplar_ids():
     return ids
 
 
-def assert_trace_visible(tid, slow_lines):
-    """The matrix invariant: one id, three observation points."""
+def assert_trace_visible(tid, slow_lines, wait_s=10.0):
+    """The matrix invariant: one id, three observation points.
+
+    The server writes the slow-log line and the latency exemplar after it
+    has handed the reply to the transport, so the client can hold the
+    reply first: those two points are waited for, up to ``wait_s``."""
+    def on_server():
+        return (any(json.loads(l).get("trace_id") == tid
+                    for l in list(slow_lines))
+                and tid in exemplar_ids())
+
+    deadline = time.monotonic() + wait_s
+    while not on_server() and time.monotonic() < deadline:
+        time.sleep(0.005)
     client_spans = trace.recent_spans(trace_id=tid, name="client.attempt")
     assert client_spans, f"no client.attempt span for {tid}"
     logged = [json.loads(l) for l in slow_lines]
@@ -447,6 +461,10 @@ EXPECTED_FAMILIES = [
     "repro_client_backoff_seconds_total",
     "repro_client_breaker_open_total",
     "repro_client_retries_total",
+    "repro_jax_backend_compiles_total",
+    "repro_jax_compile_cache_total",
+    "repro_jax_compile_seconds_total",
+    "repro_jax_lowerings_total",
     "repro_pool_shard_seconds",
     "repro_pool_straggler_redispatch_total",
     "repro_serve_binary_connections_total",
@@ -471,6 +489,7 @@ EXPECTED_FAMILIES = [
 @pytest.mark.serve
 def test_metric_name_contract():
     # touching every instrumented layer registers every family
+    compile_cache.count_compiles()
     with PredictionServer(port=0, binary_port=0).start() as srv:
         client = PredictionClient(*srv.address)
         client.argmin(small_table("contract"), "b200")

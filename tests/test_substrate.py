@@ -330,3 +330,69 @@ class TestCompileCache:
     def test_default_is_a_fixed_path_in_the_checkout(self, tmp_path):
         repo, (used, config) = self._run(tmp_path, None)
         assert used == config == os.path.join(repo, ".jax_cache")
+
+
+class TestCompileCounter:
+    """launch/compile_cache.py's listener counts compiles into the obs
+    registry: a miss that writes the cache, then a hit that loads it, and
+    nothing while the registry is switched off.  In a process of its own:
+    JAX sets its persistent cache up once per process."""
+
+    CODE = """
+        import json
+        import jax, numpy as np
+        from repro.launch import compile_cache
+        from repro.obs import metrics
+        compile_cache.enable_compile_cache()
+        compile_cache.enable_compile_cache()      # one listener still
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+        def counts():
+            snap = metrics.snapshot()
+            return {name: {",".join(s["labels"].values()): s["value"]
+                           for s in snap[name]["series"]}
+                    for name in snap if name.startswith("repro_jax_")}
+
+        f = lambda x: x * 3 + 1
+        x = np.ones(7, np.float32)                 # no compile to make it
+        jax.jit(f)(x).block_until_ready()
+        miss = counts()
+        jax.clear_caches()
+        jax.jit(f)(x).block_until_ready()
+        hit = counts()
+        metrics.set_enabled(False)
+        jax.jit(lambda x: x - 5)(x).block_until_ready()
+        print(json.dumps([miss, hit, counts()]))
+    """
+
+    def test_miss_then_hit_and_the_kill_switch(self, tmp_path):
+        import json
+        import subprocess
+        import sys
+        import textwrap
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+                   JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
+        out = subprocess.run([sys.executable, "-c",
+                              textwrap.dedent(self.CODE)], env=env,
+                             cwd=tmp_path, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr
+        miss, hit, off = json.loads(out.stdout.splitlines()[-1])
+        cache = "repro_jax_compile_cache_total"
+        secs = "repro_jax_compile_seconds_total"
+        assert miss[cache] == {"hit": 0, "miss": 1}
+        assert miss["repro_jax_lowerings_total"] == {"": 1}
+        assert miss["repro_jax_backend_compiles_total"] == {"": 1}
+        assert miss[secs]["lowering"] > 0 and miss[secs]["backend"] > 0
+        assert miss[secs]["cache_load"] == 0
+        # the same program again, from the persistent cache
+        assert hit[cache] == {"miss": 1, "hit": 1}
+        assert hit["repro_jax_lowerings_total"] == {"": 2}
+        assert hit["repro_jax_backend_compiles_total"] == {"": 2}
+        assert hit[secs]["cache_load"] > 0
+        # the load is counted once: the backend phase excludes it
+        assert hit[secs]["backend"] - miss[secs]["backend"] < \
+            miss[secs]["backend"]
+        assert off == hit
