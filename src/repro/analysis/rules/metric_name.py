@@ -46,6 +46,7 @@ LABEL_VALUES: Dict[str, frozenset] = {
     "cache": frozenset({"hit", "miss"}),
     "result": frozenset({"hit", "miss"}),
     "phase": frozenset({"lowering", "backend", "cache_load"}),
+    "path": frozenset({"sequence", "token"}),
 }
 
 #: kwargs of the registration helpers that are not labels
@@ -63,8 +64,8 @@ class MetricNameRule(Rule):
     id = "METRIC-NAME"
     hint = ("metric families follow repro_{serve,client,sweep,pool,jax}_* "
             "with label keys from the closed vocabulary "
-            "(transport/stage/reason/cache/result/phase); the family "
-            "inventory is append-only — see tests/test_obs.py "
+            "(transport/stage/reason/cache/result/phase/path); the "
+            "family inventory is append-only — see tests/test_obs.py "
             "EXPECTED_FAMILIES")
 
     def __init__(self):

@@ -96,13 +96,13 @@ def _sdpa_chunked(q, k, v, *, scale: float, causal: bool, window: int,
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, hd)
 
 
-def attn_apply(p, x, cfg: ModelConfig, *, positions=None,
-               causal: bool = True, window: int = 0,
-               kv_override: Optional[Tuple] = None):
-    """Training/prefill attention.  kv_override supplies encoder KV for
-    cross-attention (k_in, v_in already projected inputs)."""
+def _project_qkv(p, x, cfg: ModelConfig, *, positions=None,
+                 kv_override: Optional[Tuple] = None):
+    """q, k, v of a sequence: (B, S, H, hd) and (B, Skv, Hkv, hd), q and k
+    roped at ``positions`` (default 0..S-1) unless the keys and values
+    come from ``kv_override`` (cross-attention)."""
     dt = dtype_of(cfg)
-    b, s, _ = x.shape
+    s = x.shape[1]
     hd = cfg.head_dim
     q = _split_heads(x @ p["wq"].astype(dt), cfg.n_heads, hd)
     if kv_override is None:
@@ -120,9 +120,18 @@ def attn_apply(p, x, cfg: ModelConfig, *, positions=None,
                        else jnp.arange(k.shape[1]), cfg.rope_theta)
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(k, ("batch", "seq", "heads", None))
+    return q, k, v
+
+
+def _attend(p, q, k, v, cfg: ModelConfig, *, causal: bool, window: int,
+            self_attn: bool):
+    """Attention of q over k, v and the output projection: (B, S, D)."""
+    dt = dtype_of(cfg)
+    b, s = q.shape[:2]
+    hd = cfg.head_dim
     scale = hd ** -0.5
 
-    if cfg.use_flash_kernel and kv_override is None and s % 128 == 0:
+    if cfg.use_flash_kernel and self_attn and s % 128 == 0:
         from ..kernels.flash_attention import flash_attention
         o = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3), sm_scale=scale,
@@ -141,6 +150,17 @@ def attn_apply(p, x, cfg: ModelConfig, *, positions=None,
     return constrain(out, ("batch", "seq", "embed"))
 
 
+def attn_apply(p, x, cfg: ModelConfig, *, positions=None,
+               causal: bool = True, window: int = 0,
+               kv_override: Optional[Tuple] = None):
+    """Training/prefill attention.  kv_override supplies encoder KV for
+    cross-attention (k_in, v_in already projected inputs)."""
+    q, k, v = _project_qkv(p, x, cfg, positions=positions,
+                           kv_override=kv_override)
+    return _attend(p, q, k, v, cfg, causal=causal, window=window,
+                   self_attn=kv_override is None)
+
+
 # ---------------------------------------------------------------------------
 # KV cache + decode
 # ---------------------------------------------------------------------------
@@ -154,6 +174,39 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
     shape = (batch, size, cfg.n_kv_heads, cfg.head_dim)
     dt = dtype_of(cfg)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+def write_kv_prompt(cache: Dict, k, v, *, window: int = 0) -> Dict:
+    """Store a prompt's roped keys and values, (B, S, Hkv, hd) for
+    positions 0..S-1, under the slot rule of ``decode_attn_apply``:
+    position p in slot p, or p % size in a window's ring, so a prompt
+    longer than the ring leaves its last ``size`` positions there.  Slots
+    at or beyond S keep what ``cache`` held."""
+    s = k.shape[1]
+    size = cache["k"].shape[1]
+    if s > size and not (window > 0 and size == window + 1):
+        raise ValueError(f"a {s}-token prompt does not fit a {size}-slot "
+                         f"KV cache")
+    with scope("cache"):
+        if s <= size:
+            return {"k": jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], k, 0, axis=1),
+                    "v": jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], v, 0, axis=1)}
+        # positions s-size..s-1 fill the ring, position p at slot p % size
+        return {"k": jnp.roll(k[:, s - size:], s % size, axis=1),
+                "v": jnp.roll(v[:, s - size:], s % size, axis=1)}
+
+
+def prefill_attn_apply(p, x, cache: Dict, cfg: ModelConfig, *,
+                       window: int = 0):
+    """A whole prompt at positions 0..S-1: causal (windowed) attention as
+    ``attn_apply`` computes it, with the keys and values written to the
+    cache; returns (out, new_cache)."""
+    q, k, v = _project_qkv(p, x, cfg)
+    out = _attend(p, q, k, v, cfg, causal=True, window=window,
+                  self_attn=True)
+    return out, write_kv_prompt(cache, k, v, window=window)
 
 
 def decode_attn_apply(p, x, cache: Dict, pos, cfg: ModelConfig, *,

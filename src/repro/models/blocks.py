@@ -83,6 +83,19 @@ def _attn_decode(p, x, cache, pos, cfg: ModelConfig, *, window=0,
     return x, {"kv": kv}
 
 
+def _attn_prefill(p, x, cache, cfg: ModelConfig, *, window=0):
+    """The whole prompt through the block, its keys and values into the
+    cache."""
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        o, kv = attn_mod.prefill_attn_apply(p["attn"], h, cache["kv"], cfg,
+                                            window=window)
+        x = x + o
+    if "mlp" in p:
+        x = _mlp_residual(p, x, cfg)
+    return x, {"kv": kv}
+
+
 # --------------------------------------------------------------------------
 # moe (attention = MLA or GQA, FFN = MoE)
 # --------------------------------------------------------------------------
@@ -246,11 +259,16 @@ def _enc_block_apply(p, x, cfg: ModelConfig, *, memory=None):
 # --------------------------------------------------------------------------
 
 class BlockDef:
-    def __init__(self, init, apply, cache, decode):
+    """``prefill(p, x, cache, cfg) -> (x, new_cache)`` takes a whole
+    prompt at positions 0..S-1 at once; None where the block's state can
+    only be built one token at a time through ``decode``."""
+
+    def __init__(self, init, apply, cache, decode, prefill=None):
         self.init = init
         self.apply = apply
         self.cache = cache
         self.decode = decode
+        self.prefill = prefill
 
 
 REGISTRY: Dict[str, BlockDef] = {
@@ -259,14 +277,17 @@ REGISTRY: Dict[str, BlockDef] = {
         lambda p, x, cfg, **kw: _attn_block_apply(p, x, cfg, window=0, **kw),
         lambda cfg, b, m, **kw: _attn_cache(cfg, b, m, window=0),
         lambda p, x, c, pos, cfg, **kw: _attn_decode(p, x, c, pos, cfg,
-                                                     window=0, **kw)),
+                                                     window=0, **kw),
+        lambda p, x, c, cfg: _attn_prefill(p, x, c, cfg, window=0)),
     "local_attn": BlockDef(
         _attn_block_init,
         lambda p, x, cfg, **kw: _attn_block_apply(
             p, x, cfg, window=cfg.window, **kw),
         lambda cfg, b, m, **kw: _attn_cache(cfg, b, m, window=cfg.window),
         lambda p, x, c, pos, cfg, **kw: _attn_decode(
-            p, x, c, pos, cfg, window=cfg.window, **kw)),
+            p, x, c, pos, cfg, window=cfg.window, **kw),
+        lambda p, x, c, cfg: _attn_prefill(p, x, c, cfg,
+                                           window=cfg.window)),
     "moe": BlockDef(_moe_block_init, _moe_block_apply, _moe_cache,
                     _moe_decode),
     "ssm": BlockDef(_ssm_block_init, _ssm_block_apply, _ssm_cache,

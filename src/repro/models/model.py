@@ -15,8 +15,22 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
+from ..obs import metrics
 from .blocks import REGISTRY
 from .layers import dtype_of, embed_init, pdtype_of, rmsnorm, scope
+
+PREFILL_PATHS = ("sequence", "token")
+
+
+def _prefill_lowerings(path: str) -> metrics.Counter:
+    return metrics.counter("repro_jax_prefill_lowerings_total",
+                           "prefill programs traced, by path: one "
+                           "sequence-parallel pass or a loop over tokens",
+                           path=path)
+
+
+for _path in PREFILL_PATHS:
+    _prefill_lowerings(_path)
 
 
 class LanguageModel:
@@ -325,8 +339,63 @@ class LanguageModel:
         return x, jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
 
     def prefill(self, params, tokens, cache: Dict, *, memory_embeds=None):
-        """Sequential prefill through decode_step (exactness over speed;
-        the dry-run lowers ``forward`` for prefill compute instead)."""
+        """tokens: (B, S) prompt; cache from ``init_cache`` -> (logits of
+        the last token (B, V) in the config's dtype, the cache filled
+        through position S-1).
+
+        When every block can take the whole prompt at once (each kind's
+        ``BlockDef.prefill``: the attention blocks) and there is no
+        memory, one sequence-parallel pass: each layer attends over all
+        S positions as ``forward`` does and writes its S keys and values
+        to its cache in one store, and only the last position goes
+        through the head.  Other models (recurrent state, experts,
+        memory) run ``decode_step`` over the prompt one token at a time.
+        Each trace counts its path in
+        ``repro_jax_prefill_lowerings_total{path}``."""
+        cfg = self.cfg
+        kinds = cfg.pattern + (("attn",) if cfg.first_dense > 0 else ())
+        sequence = (memory_embeds is None and cfg.enc_layers == 0
+                    and all(REGISTRY[k].prefill for k in kinds))
+        _prefill_lowerings("sequence" if sequence else "token").inc()
+        if sequence:
+            return self._prefill_sequence(params, tokens, cache)
+        return self._prefill_tokens(params, tokens, cache,
+                                    memory_embeds=memory_embeds)
+
+    def _prefill_sequence(self, params, tokens, cache: Dict):
+        cfg = self.cfg
+        x = self._embed(params, tokens)
+        new_cache: Dict[str, Any] = {}
+
+        if cfg.first_dense > 0:
+            dense_cfg = cfg.replace(pattern=("attn",))
+
+            def pre_body(h, inp):
+                bp, bc = inp
+                return REGISTRY["attn"].prefill(bp, h, bc, dense_cfg)
+
+            with scope("layers"):
+                x, new_cache["prefix"] = self._decode_layers(
+                    pre_body, x, params["prefix"], cache["prefix"],
+                    cfg.first_dense)
+
+        def body(h, inp):
+            gp, gc = inp
+            ncs = {}
+            for i, kind in enumerate(cfg.pattern):
+                h, ncs[f"b{i}"] = REGISTRY[kind].prefill(
+                    gp[f"b{i}"], h, gc[f"b{i}"], cfg)
+            return h, ncs
+
+        with scope("layers"):
+            x, new_cache["groups"] = self._decode_layers(
+                body, x, params["groups"], cache["groups"], cfg.n_groups)
+        logits = self._head(params, x[:, -1:, :])[:, 0, :]
+        return logits, new_cache
+
+    def _prefill_tokens(self, params, tokens, cache: Dict, *,
+                        memory_embeds=None):
+        """The prompt through ``decode_step``, one token at a time."""
         s = tokens.shape[1]
 
         def body(carry, t):

@@ -17,9 +17,10 @@ from ..obs import metrics
 def make_prefill(model: LanguageModel) -> Callable:
     """prefill(params, tokens[, memory_embeds]) -> last-token logits.
 
-    Lowered for the prefill_* cells: the dominant prefill compute is the
-    full forward; per-layer cache population adds stores the roofline
-    memory term already covers (DESIGN.md §4)."""
+    Lowered for the prefill_* dry-run cells: the full forward, with no
+    cache.  Serving fills its cache with ``LanguageModel.prefill``, which
+    for attention models is the same sequence-parallel pass plus one
+    store of each layer's keys and values."""
 
     def prefill(params, tokens, memory_embeds=None):
         logits, _ = model.forward(params, tokens,
@@ -53,21 +54,29 @@ def greedy_generate(model: LanguageModel, params, prompt, *, max_new: int,
     """Batched greedy decoding driver (example/serving path).
 
     Under ``sharding.use_mesh`` the cache is placed by
-    ``cache_specs_tree``.  Every jitted lowering after the first decode
-    step is counted (``repro_jax_lowerings_total``): a steady decode loop
-    compiles nothing."""
+    ``cache_specs_tree`` and kept there: the prefill's cache is put back
+    in that layout and every decode step returns it in it, so the loop's
+    input layouts never change.  Every jitted lowering after the first
+    decode step is counted (``repro_jax_lowerings_total``): a steady
+    decode loop compiles nothing."""
     b, s = prompt.shape
     max_len = max_len or (s + max_new)
     cache = model.init_cache(b, max_len)
     mesh = sharding.active_mesh()
     if mesh is not None:
-        cache = jax.device_put(cache, sharding.tree_shardings(
-            mesh, sharding.cache_specs_tree(cache, mesh=mesh)))
+        layout = sharding.tree_shardings(
+            mesh, sharding.cache_specs_tree(cache, mesh=mesh))
+        cache = jax.device_put(cache, layout)
     # prefill fills the cache through position s-1 and returns the
     # last-token logits
     logits, cache = model.prefill(params, prompt, cache,
                                   memory_embeds=memory_embeds)
-    step = jax.jit(model.decode_step)
+    if mesh is None:
+        step = jax.jit(model.decode_step)
+    else:
+        cache = jax.device_put(cache, layout)
+        step = jax.jit(model.decode_step,
+                       out_shardings=(logits.sharding, layout))
     compile_cache.count_compiles()
 
     toks, outs, before = [], [], None

@@ -483,6 +483,7 @@ EXPECTED_FAMILIES = [
     "repro_serve_stage_seconds",
     "repro_sweep_predict_table_seconds",
     "repro_sweep_rows_total",
+    "repro_jax_prefill_lowerings_total",
 ]
 
 
@@ -490,6 +491,7 @@ EXPECTED_FAMILIES = [
 def test_metric_name_contract():
     # touching every instrumented layer registers every family
     compile_cache.count_compiles()
+    import repro.models.model  # noqa: F401  (its import registers prefill's)
     with PredictionServer(port=0, binary_port=0).start() as srv:
         client = PredictionClient(*srv.address)
         client.argmin(small_table("contract"), "b200")

@@ -107,3 +107,25 @@ def test_danube_decode_step_fits_one_v5e(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 3.5e9      # 1.8B bf16 params
     assert total < V5E_HBM_BYTES, total
+
+
+def test_danube_prefill_fits_one_v5e(one_chip):
+    """The prefill chip_smoke.py serves: batch 8, a 1,024-token prompt into
+    a 1,088-slot cache, full published config, in one sequence-parallel
+    pass (no token loop left in the program)."""
+    model = build(DANUBE)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(model.init_cache(8, 1088, abstract=True))
+    tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.prefill).lower(params, tokens, cache).compile()
+    # one while loop: the layer scan
+    assert compiled.as_text().count(" while(") == 1
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
