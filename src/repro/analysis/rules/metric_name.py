@@ -47,6 +47,7 @@ LABEL_VALUES: Dict[str, frozenset] = {
     "result": frozenset({"hit", "miss"}),
     "phase": frozenset({"lowering", "backend", "cache_load"}),
     "path": frozenset({"sequence", "token"}),
+    "form": frozenset({"expanded", "absorbed"}),
 }
 
 #: kwargs of the registration helpers that are not labels
@@ -64,7 +65,7 @@ class MetricNameRule(Rule):
     id = "METRIC-NAME"
     hint = ("metric families follow repro_{serve,client,sweep,pool,jax}_* "
             "with label keys from the closed vocabulary "
-            "(transport/stage/reason/cache/result/phase/path); the "
+            "(transport/stage/reason/cache/result/phase/path/form); the "
             "family inventory is append-only — see tests/test_obs.py "
             "EXPECTED_FAMILIES")
 

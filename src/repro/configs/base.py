@@ -39,11 +39,20 @@ class ModelConfig:
     pattern: Tuple[str, ...] = ("attn",)
 
     # --- MoE -----------------------------------------------------------------
-    n_experts: int = 0
+    # d_ff is the width of the dense layers (the first_dense prefix too);
+    # the experts have their own, d_expert.
+    n_experts: int = 0             # routed experts held: ids [expert_lo,
+                                   # expert_lo + n_experts) of the router's
+    n_routed_experts: int = 0      # router width (0 -> n_experts)
+    expert_lo: int = 0             # first routed expert id held
     top_k: int = 0
     n_shared_experts: int = 0
     d_expert: int = 0              # expert FFN width (d_ff used if 0)
-    capacity_factor: float = 1.25
+    router_score: str = "softmax"  # softmax | sigmoid (+ a correction bias
+                                   # that only chooses: DeepSeek-V3 noaux_tc)
+    n_group: int = 1               # experts chosen from the topk_group
+    topk_group: int = 1            # groups whose 2 best scores sum highest
+    routed_scaling_factor: float = 1.0
     router_aux_coef: float = 0.001
     moe_every: int = 1             # MoE layer every k-th block (1 = all)
     first_dense: int = 0           # leading dense blocks before MoE starts
@@ -53,6 +62,12 @@ class ModelConfig:
     kv_lora_rank: int = 512
     q_lora_rank: int = 0           # 0 -> no q compression
     rope_head_dim: int = 64
+    v_head_dim: int = 0            # 0 -> head_dim (the q/k "nope" width)
+    # YaRN rotary scaling (0 = off): frequencies below the correction range
+    # divided by the factor, and the softmax scale multiplied by the mscale
+    # term squared (DeepSeek-V3's rope_scaling; models/layers.py)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
 
     # --- MTP (deepseek-v3 multi-token prediction) ----------------------------
     mtp_depth: int = 0
@@ -100,6 +115,15 @@ class ModelConfig:
                                self.d_model // max(self.n_heads, 1))
         if self.n_heads and self.n_heads % max(self.n_kv_heads, 1) != 0:
             raise ValueError("n_heads must be divisible by n_kv_heads")
+        if self.expert_lo + self.n_experts > self.router_width:
+            raise ValueError(
+                f"experts held [{self.expert_lo}, "
+                f"{self.expert_lo + self.n_experts}) exceed the router's "
+                f"{self.router_width}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score {self.router_score!r}")
+        if self.router_width % self.n_group:
+            raise ValueError("n_routed_experts must divide into n_group")
         if (self.n_layers - self.first_dense) % len(self.pattern) != 0:
             raise ValueError(
                 f"n_layers={self.n_layers} minus first_dense="
@@ -123,6 +147,20 @@ class ModelConfig:
     def expert_ff(self) -> int:
         return self.d_expert or self.d_ff
 
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.n_experts
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def prefix_kind(self) -> str:
+        """Block kind of the ``first_dense`` leading layers: the model's
+        attention with a dense SwiGLU of width d_ff."""
+        return "mla" if self.use_mla else "attn"
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -140,11 +178,13 @@ def _attn_params(cfg: ModelConfig, kind: str) -> int:
     d, hd = cfg.d_model, cfg.head_dim
     if kind == "mla":
         qd = hd + cfg.rope_head_dim
+        vd = cfg.value_dim
         q = d * cfg.n_heads * qd if cfg.q_lora_rank == 0 else \
-            d * cfg.q_lora_rank + cfg.q_lora_rank * cfg.n_heads * qd
-        kv = d * (cfg.kv_lora_rank + cfg.rope_head_dim) \
-            + cfg.kv_lora_rank * cfg.n_heads * (hd + hd)
-        o = cfg.n_heads * hd * d
+            d * cfg.q_lora_rank + cfg.q_lora_rank \
+            + cfg.q_lora_rank * cfg.n_heads * qd
+        kv = d * (cfg.kv_lora_rank + cfg.rope_head_dim) + cfg.kv_lora_rank \
+            + cfg.kv_lora_rank * cfg.n_heads * (hd + vd)
+        o = cfg.n_heads * vd * d
         return q + kv + o
     q = d * cfg.n_heads * hd
     kv = 2 * d * cfg.n_kv_heads * hd
@@ -157,7 +197,9 @@ def _mlp_params(cfg: ModelConfig, width: int) -> int:
 
 
 def _moe_params(cfg: ModelConfig, active_only: bool) -> int:
-    router = cfg.d_model * cfg.n_experts
+    router = cfg.d_model * cfg.router_width
+    if cfg.router_score == "sigmoid":
+        router += cfg.router_width              # choice-correction bias
     n_routed = cfg.top_k if active_only else cfg.n_experts
     routed = n_routed * _mlp_params(cfg, cfg.expert_ff)
     shared = cfg.n_shared_experts * _mlp_params(cfg, cfg.expert_ff)
@@ -189,6 +231,8 @@ def _block_params(cfg: ModelConfig, kind: str, active_only: bool) -> int:
             (_mlp_params(cfg, cfg.d_ff) if cfg.d_ff else 0)
         if not cfg.d_ff:
             norms = cfg.d_model
+    elif kind == "mla":
+        body = _attn_params(cfg, "mla") + _mlp_params(cfg, cfg.d_ff)
     elif kind == "cross_attn":
         # self-attn + gated cross-attn + mlp, 3 norms + gate scalar
         body = 2 * _attn_params(cfg, "gqa") + _mlp_params(cfg, cfg.d_ff) + 1
@@ -214,7 +258,8 @@ def _count_params(cfg: ModelConfig, active_only: bool = False) -> int:
     total += cfg.d_model                    # final norm
     per_group = sum(_block_params(cfg, k, active_only) for k in cfg.pattern)
     total += cfg.n_groups * per_group
-    total += cfg.first_dense * _block_params(cfg, "attn", active_only)
+    total += cfg.first_dense * _block_params(cfg, cfg.prefix_kind,
+                                             active_only)
     if cfg.enc_layers:
         # encoder stack (attn blocks) + encoder final norm
         total += cfg.enc_layers * _block_params(cfg, "attn", active_only)

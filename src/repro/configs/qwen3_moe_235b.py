@@ -18,7 +18,6 @@ CONFIG = ModelConfig(
     top_k=8,
     n_shared_experts=0,
     d_expert=1536,
-    capacity_factor=1.25,
     rope_theta=1000000.0,
     dtype="bfloat16",
     param_dtype="bfloat16",
@@ -38,7 +37,6 @@ SMOKE = CONFIG.replace(
     vocab=128,
     n_experts=8,
     top_k=2,
-    capacity_factor=4.0,
     dtype="float32",
     param_dtype="float32",
     remat="none",

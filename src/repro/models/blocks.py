@@ -5,6 +5,8 @@ the block part it feeds (``layers.SCOPES``).  Kinds:
   attn        full causal GQA attention + SwiGLU MLP
   local_attn  sliding-window GQA attention + MLP
   moe         (MLA or GQA) attention + MoE FFN (returns aux loss)
+  mla         MLA attention + SwiGLU MLP (DeepSeek-V3's leading dense
+              layers, width d_ff)
   ssm         Mamba2 mixer (no MLP; the block IS the mixer)
   rglru       RG-LRU recurrent mixer + MLP
   cross_attn  self-attn + cross-attn(memory) + MLP (whisper dec / vlm)
@@ -100,34 +102,27 @@ def _attn_prefill(p, x, cache, cfg: ModelConfig, *, window=0):
 # moe (attention = MLA or GQA, FFN = MoE)
 # --------------------------------------------------------------------------
 
-def _moe_block_init(key, cfg: ModelConfig):
-    k1, k2 = jax.random.split(key)
-    attn_p = (mla_mod.mla_init(k1, cfg) if cfg.use_mla
-              else attn_mod.attn_init(k1, cfg))
-    return {"ln1": _norm_init(cfg), "attn": attn_p,
-            "ln2": _norm_init(cfg), "moe": moe_mod.moe_init(k2, cfg)}
+def _attention_init(key, cfg: ModelConfig):
+    return (mla_mod.mla_init(key, cfg) if cfg.use_mla
+            else attn_mod.attn_init(key, cfg))
 
 
-def _moe_block_apply(p, x, cfg: ModelConfig, *, memory=None):
+def _attention_apply(p, x, cfg: ModelConfig):
+    """x + the block's causal self-attention (MLA or GQA)."""
     with scope("attn"):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         if cfg.use_mla:
-            x = x + mla_mod.mla_apply(p["attn"], h, cfg)
-        else:
-            x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
-    with scope("moe"):
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        y, aux = moe_mod.moe_apply(p["moe"], h, cfg)
-        return x + y, aux
+            return x + mla_mod.mla_apply(p["attn"], h, cfg)
+        return x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
 
 
-def _moe_cache(cfg, batch, max_len, **kw):
+def _attention_cache(cfg, batch, max_len):
     if cfg.use_mla:
         return {"mla": mla_mod.init_mla_cache(cfg, batch, max_len)}
     return {"kv": attn_mod.init_kv_cache(cfg, batch, max_len)}
 
 
-def _moe_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
+def _attention_decode(p, x, cache, pos, cfg: ModelConfig):
     with scope("attn"):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         if cfg.use_mla:
@@ -138,11 +133,76 @@ def _moe_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
             o, new = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"],
                                                 pos, cfg)
             new_cache = {"kv": new}
-        x = x + o
-    with scope("moe"):
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        y, _ = moe_mod.moe_apply(p["moe"], h, cfg)
-        return x + y, new_cache
+        return x + o, new_cache
+
+
+def _attention_prefill(p, x, cache, cfg: ModelConfig):
+    with scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if cfg.use_mla:
+            o, new = mla_mod.mla_prefill(p["attn"], h, cache["mla"], cfg)
+            new_cache = {"mla": new}
+        else:
+            o, new = attn_mod.prefill_attn_apply(p["attn"], h, cache["kv"],
+                                                 cfg)
+            new_cache = {"kv": new}
+        return x + o, new_cache
+
+
+def _moe_block_init(key, cfg: ModelConfig):
+    k1, k2 = jax.random.split(key)
+    return {"ln1": _norm_init(cfg), "attn": _attention_init(k1, cfg),
+            "ln2": _norm_init(cfg), "moe": moe_mod.moe_init(k2, cfg)}
+
+
+@scope("moe")
+def _moe_residual(p, x, cfg: ModelConfig):
+    """(x + MoE(norm(x)), aux loss)."""
+    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+    y, aux = moe_mod.moe_apply(p["moe"], h, cfg)
+    return x + y, aux
+
+
+def _moe_block_apply(p, x, cfg: ModelConfig, *, memory=None):
+    return _moe_residual(p, _attention_apply(p, x, cfg), cfg)
+
+
+def _moe_cache(cfg, batch, max_len, **kw):
+    return _attention_cache(cfg, batch, max_len)
+
+
+def _moe_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
+    x, new_cache = _attention_decode(p, x, cache, pos, cfg)
+    return _moe_residual(p, x, cfg)[0], new_cache
+
+
+def _moe_prefill(p, x, cache, cfg: ModelConfig):
+    x, new_cache = _attention_prefill(p, x, cache, cfg)
+    return _moe_residual(p, x, cfg)[0], new_cache
+
+
+# --------------------------------------------------------------------------
+# mla (MLA attention + dense SwiGLU: the leading dense layers)
+# --------------------------------------------------------------------------
+
+def _mla_block_init(key, cfg: ModelConfig):
+    k1, k2 = jax.random.split(key)
+    return {"ln1": _norm_init(cfg), "attn": mla_mod.mla_init(k1, cfg),
+            "ln2": _norm_init(cfg), "mlp": mlp_init(k2, cfg)}
+
+
+def _mla_block_apply(p, x, cfg: ModelConfig, *, memory=None):
+    return _mlp_residual(p, _attention_apply(p, x, cfg), cfg), _no_aux()
+
+
+def _mla_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
+    x, new_cache = _attention_decode(p, x, cache, pos, cfg)
+    return _mlp_residual(p, x, cfg), new_cache
+
+
+def _mla_prefill(p, x, cache, cfg: ModelConfig):
+    x, new_cache = _attention_prefill(p, x, cache, cfg)
+    return _mlp_residual(p, x, cfg), new_cache
 
 
 # --------------------------------------------------------------------------
@@ -289,7 +349,10 @@ REGISTRY: Dict[str, BlockDef] = {
         lambda p, x, c, cfg: _attn_prefill(p, x, c, cfg,
                                            window=cfg.window)),
     "moe": BlockDef(_moe_block_init, _moe_block_apply, _moe_cache,
-                    _moe_decode),
+                    _moe_decode, _moe_prefill),
+    "mla": BlockDef(_mla_block_init, _mla_block_apply,
+                    lambda cfg, b, m, **kw: _attention_cache(cfg, b, m),
+                    _mla_decode, _mla_prefill),
     "ssm": BlockDef(_ssm_block_init, _ssm_block_apply, _ssm_cache,
                     _ssm_decode),
     "rglru": BlockDef(_rglru_block_init, _rglru_block_apply, _rglru_cache,

@@ -1,6 +1,7 @@
 """Shared layers: init helpers, RMSNorm, rotary embeddings, SwiGLU MLP."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -62,15 +63,45 @@ def rmsnorm(x, scale, eps: float = 1e-6, *, use_kernel: bool = False):
 # rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+#: YaRN's correction range, in turns over the original context: DeepSeek-V3's
+#: rope_scaling beta_fast and beta_slow, the only values in use
+YARN_BETA_FAST = 32.0
+YARN_BETA_SLOW = 1.0
+
+
+def rope_freqs(head_dim: int, theta: float, yarn=None):
+    """Inverse frequencies theta^(-2i/d); with ``yarn`` (factor, original
+    max positions), YaRN's: the frequencies that turn fewer than
+    YARN_BETA_SLOW times over the original context divided by the factor,
+    those that turn more than YARN_BETA_FAST times kept, and a linear ramp
+    between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                        dtype=jnp.float32) / head_dim))
+    if yarn is None:
+        return freqs
+    factor, original = yarn
+
+    def dim_of(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(dim_of(YARN_BETA_SLOW)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (B, S, H, D_even); positions: (B, S) or (S,)."""
+def yarn_mscale(factor: float) -> float:
+    """YaRN's attention temperature term, 0.1 ln(factor) + 1 (mscale 1)."""
+    return 1.0 if factor <= 1 else 0.1 * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float, *, yarn=None):
+    """x: (B, S, H, D_even); positions: (B, S) or (S,).  Rotate-half form;
+    ``yarn`` as in ``rope_freqs``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    freqs = rope_freqs(d, theta, yarn)                 # (d/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, d/2)

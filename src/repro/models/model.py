@@ -58,13 +58,11 @@ class LanguageModel:
         if cfg.first_dense > 0:
             pre_keys = jax.random.split(k_pre, cfg.first_dense)
             params["prefix"] = jax.vmap(
-                lambda k: REGISTRY["attn"].init(k, cfg.replace(
-                    pattern=("attn",))))(pre_keys)
+                lambda k: REGISTRY[cfg.prefix_kind].init(
+                    k, self._prefix_cfg()))(pre_keys)
         if not cfg.tie_embeddings:
-            params["lm_head"] = embed_init(k_head, cfg.d_model,
-                                           cfg.vocab, pd).T.copy() \
-                if False else (jax.random.normal(
-                    k_head, (cfg.d_model, cfg.vocab)) * 0.02).astype(pd)
+            params["lm_head"] = (jax.random.normal(
+                k_head, (cfg.d_model, cfg.vocab)) * 0.02).astype(pd)
         if cfg.enc_layers > 0:
             enc_keys = jax.random.split(k_enc, cfg.enc_layers)
             params["encoder"] = {
@@ -84,6 +82,10 @@ class LanguageModel:
                 "norm_e": jnp.ones((cfg.d_model,), pd),
             }
         return params
+
+    def _prefix_cfg(self) -> ModelConfig:
+        """The config the ``first_dense`` leading layers run under."""
+        return self.cfg.replace(pattern=(self.cfg.prefix_kind,))
 
     # -------------------------------------------------------------- forward
     @scope("embed")
@@ -145,17 +147,18 @@ class LanguageModel:
         cfg = self.cfg
         if cfg.first_dense == 0:
             return x
-        dense_cfg = cfg.replace(pattern=("attn",))
+        dense_cfg = self._prefix_cfg()
+        block = REGISTRY[cfg.prefix_kind]
 
         if cfg.scan_layers:
             def body(h, bparams):
-                h, _ = REGISTRY["attn"].apply(bparams, h, dense_cfg)
+                h, _ = block.apply(bparams, h, dense_cfg)
                 return h, None
             x, _ = jax.lax.scan(body, x, params["prefix"])
         else:
             for i in range(cfg.first_dense):
                 bp = jax.tree.map(lambda a: a[i], params["prefix"])
-                x, _ = REGISTRY["attn"].apply(bp, x, dense_cfg)
+                x, _ = block.apply(bp, x, dense_cfg)
         return x
 
     def _encode(self, params, frame_embeds):
@@ -278,7 +281,7 @@ class LanguageModel:
         cache: Dict[str, Any] = {"groups": jax.tree.map(stack, proto)}
         if cfg.first_dense > 0:
             pre = jax.eval_shape(
-                lambda: REGISTRY["attn"].cache(cfg, batch, max_len))
+                lambda: REGISTRY[cfg.prefix_kind].cache(cfg, batch, max_len))
             stack_p = (lambda a: jax.ShapeDtypeStruct(
                 (cfg.first_dense,) + a.shape, a.dtype)) if abstract else \
                 (lambda a: jnp.zeros((cfg.first_dense,) + a.shape, a.dtype))
@@ -300,12 +303,12 @@ class LanguageModel:
         new_cache: Dict[str, Any] = {}
 
         if cfg.first_dense > 0:
-            dense_cfg = cfg.replace(pattern=("attn",))
+            dense_cfg = self._prefix_cfg()
 
             def pre_body(h, inp):
                 bp, bc = inp
-                h, nc = REGISTRY["attn"].decode(bp, h, bc, pos, dense_cfg)
-                return h, nc
+                return REGISTRY[cfg.prefix_kind].decode(bp, h, bc, pos,
+                                                        dense_cfg)
 
             with scope("layers"):
                 x, new_cache["prefix"] = self._decode_layers(
@@ -344,16 +347,18 @@ class LanguageModel:
         through position S-1).
 
         When every block can take the whole prompt at once (each kind's
-        ``BlockDef.prefill``: the attention blocks) and there is no
-        memory, one sequence-parallel pass: each layer attends over all
-        S positions as ``forward`` does and writes its S keys and values
-        to its cache in one store, and only the last position goes
-        through the head.  Other models (recurrent state, experts,
-        memory) run ``decode_step`` over the prompt one token at a time.
+        ``BlockDef.prefill``: the attention, latent-attention and expert
+        blocks) and there is no memory, one sequence-parallel pass: each
+        layer attends over all S positions as ``forward`` does and writes
+        its S keys and values (or latents) to its cache in one store, and
+        only the last position goes through the head.  Other models
+        (recurrent state, memory) run ``decode_step`` over the prompt one
+        token at a time.
         Each trace counts its path in
         ``repro_jax_prefill_lowerings_total{path}``."""
         cfg = self.cfg
-        kinds = cfg.pattern + (("attn",) if cfg.first_dense > 0 else ())
+        kinds = cfg.pattern + ((cfg.prefix_kind,) if cfg.first_dense > 0
+                               else ())
         sequence = (memory_embeds is None and cfg.enc_layers == 0
                     and all(REGISTRY[k].prefill for k in kinds))
         _prefill_lowerings("sequence" if sequence else "token").inc()
@@ -368,11 +373,12 @@ class LanguageModel:
         new_cache: Dict[str, Any] = {}
 
         if cfg.first_dense > 0:
-            dense_cfg = cfg.replace(pattern=("attn",))
+            dense_cfg = self._prefix_cfg()
 
             def pre_body(h, inp):
                 bp, bc = inp
-                return REGISTRY["attn"].prefill(bp, h, bc, dense_cfg)
+                return REGISTRY[cfg.prefix_kind].prefill(bp, h, bc,
+                                                         dense_cfg)
 
             with scope("layers"):
                 x, new_cache["prefix"] = self._decode_layers(

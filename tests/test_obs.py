@@ -484,6 +484,7 @@ EXPECTED_FAMILIES = [
     "repro_sweep_predict_table_seconds",
     "repro_sweep_rows_total",
     "repro_jax_prefill_lowerings_total",
+    "repro_jax_mla_lowerings_total",
 ]
 
 

@@ -1,8 +1,10 @@
 """LanguageModel.prefill's two paths: one sequence-parallel pass for models
-made of attention blocks, and ``_prefill_tokens``, ``decode_step`` over
-the prompt one token at a time, which every other model keeps.  From the
-same prompt and cache both must give the same last-token logits and the
-same cache, and decoding on from either must agree."""
+made of attention, latent-attention and expert blocks, and
+``_prefill_tokens``, ``decode_step`` over the prompt one token at a time,
+which every other model keeps.  From the same prompt and cache both must
+give the same last-token logits and the same cache, and decoding on from
+either must agree.  For latent attention this also holds the expanded
+form (the pass) to the absorbed one (decode)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,10 @@ from repro.obs import metrics
 BASE = ModelConfig(name="tiny-prefill", family="dense", n_layers=2,
                    d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, vocab=128)
 LOCAL = BASE.replace(pattern=("local_attn",), window=7)   # an 8-slot ring
+# DeepSeek-V3's blocks: MLA prefix layer, then MLA + held experts (4 of
+# 8, from id 2, noaux_tc over 4 groups) with a shared expert
+DSV3 = get_config("deepseek-v3-671b", smoke=True).replace(
+    n_experts=4, n_routed_experts=8, expert_lo=2)
 DECODE_STEPS = 4
 
 # config, prompt length, cache length
@@ -25,6 +31,8 @@ CASES = {
     "local_attn-equal-to-ring": (LOCAL, 8, 20),
     "local_attn-ring-wraps": (LOCAL, 13, 20),
     "first_dense-prefix": (BASE.replace(n_layers=3, first_dense=1), 12, 16),
+    "mla-moe-held-share": (DSV3, 12, 16),
+    "gqa-moe": (get_config("qwen3-moe-235b-a22b", smoke=True), 12, 16),
 }
 # float32 agrees to rounding; bfloat16 to test_bf16_prefill_matches_forward's
 # share of the largest logit
@@ -94,7 +102,8 @@ def prefill_lowerings():
     ("h2o-danube-1.8b", "sequence"),        # local_attn
     ("mamba2-1.3b", "token"),               # ssm
     ("recurrentgemma-9b", "token"),         # rglru (and local_attn)
-    ("qwen3-moe-235b-a22b", "token"),       # moe
+    ("qwen3-moe-235b-a22b", "sequence"),    # moe (GQA)
+    ("deepseek-v3-671b", "sequence"),       # mla prefix, moe (MLA)
     ("llama-3.2-vision-90b", "token"),      # cross_attn over image memory
 ])
 def test_prefill_path_follows_the_block_kinds(arch, path):

@@ -129,3 +129,45 @@ def test_danube_prefill_fits_one_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+def _dsv3_cell_model():
+    """The chip benchmark's cut DeepSeek-V3: 3 dense and 4 expert layers,
+    8 of 256 experts held, a quarter of the vocabulary, published widths."""
+    import json
+    from pathlib import Path
+
+    from repro.configs.base import ModelConfig
+
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / "deepseek-v3-671b.json")
+    m = json.loads(path.read_text())["model"]
+    return build(ModelConfig(**dict(m, pattern=tuple(m["pattern"]))))
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode_step"])
+def test_dsv3_cell_fits_one_v5e(one_chip, program):
+    """The DeepSeek-V3 cell's programs: batch 4, a 4,096-token prompt into
+    a 4,224-slot latent cache, and one decode step; 9.1 GB of weights and
+    what each program needs beside them fit one chip's HBM."""
+    model = _dsv3_cell_model()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(model.init_cache(4, 4224, abstract=True))
+    if program == "prefill":
+        args = (params, jax.ShapeDtypeStruct((4, 4096), jnp.int32,
+                                             sharding=one_chip), cache)
+    else:
+        args = (params, cache,
+                jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    compiled = jax.jit(getattr(model, program)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 9.1e9      # 4,555 M bf16 params
+    assert total < 15.75e9, total
