@@ -17,5 +17,8 @@ Kernels:
 The paper's own hot-spots are GEMM/attention-class kernels (its validation
 classes); ``matmul`` doubles as the tensor-throughput microbenchmark from
 §V-A, adapted from CUDA tiles to MXU-aligned BlockSpecs.
+
+Subpackages are imported by name (``repro.kernels.ssd`` ...), never here:
+importing Pallas takes seconds of a process's start, which a model whose
+path runs no kernel should not pay.
 """
-from . import flash_attention, matmul, rmsnorm, ssd  # noqa: F401

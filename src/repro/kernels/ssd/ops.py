@@ -1,4 +1,4 @@
-"""Public SSD op: Pallas chunked scan with jnp-scan fallback."""
+"""Public SSD op: Pallas chunked scan with a chunked-jnp fallback."""
 from __future__ import annotations
 
 from typing import Optional
@@ -13,7 +13,7 @@ def ssd_scan(x, dt, a_log, b, c, *, chunk: int = kernel.DEFAULT_CHUNK,
     """Mamba2 SSD: x (B,S,H,P), dt (B,S,H) > 0, a_log (H,), b/c (B,S,N).
 
     Paths: Pallas kernel (TPU target) > chunked jnp (XLA fallback /
-    dry-run) > exact sequential scan (odd lengths)."""
+    dry-run; a ragged tail padded with dt = 0)."""
     interpret = resolve_interpret(interpret)
     s = x.shape[1]
     eff_chunk = min(chunk, s)
@@ -25,8 +25,6 @@ def ssd_scan(x, dt, a_log, b, c, *, chunk: int = kernel.DEFAULT_CHUNK,
             in_domain, interpret, f"ssd seq {s} chunk {eff_chunk}"):
         return kernel.ssd(x, dt, a_log, b, c, chunk=eff_chunk,
                           interpret=interpret)
-    if s % eff_chunk != 0:
-        return ref.ssd_scan_ref(x, dt, a_log, b, c)
     return ref.ssd_chunked_jnp(x, dt, a_log, b, c, chunk=eff_chunk,
                                unroll_heads=unroll_heads,
                                head_blocks=head_blocks)

@@ -230,6 +230,15 @@ def _ssm_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
     return x + o, {"ssm": new}
 
 
+@scope("ssm")
+def _ssm_prefill(p, x, cache, cfg: ModelConfig):
+    """The whole prompt through the mixer in one chunked pass, its conv
+    window and final state into the cache."""
+    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    o, new = ssm_mod.ssm_prefill(p["ssm"], h, cache["ssm"], cfg)
+    return x + o, {"ssm": new}
+
+
 # --------------------------------------------------------------------------
 # rglru
 # --------------------------------------------------------------------------
@@ -320,8 +329,10 @@ def _enc_block_apply(p, x, cfg: ModelConfig, *, memory=None):
 
 class BlockDef:
     """``prefill(p, x, cache, cfg) -> (x, new_cache)`` takes a whole
-    prompt at positions 0..S-1 at once; None where the block's state can
-    only be built one token at a time through ``decode``."""
+    prompt at positions 0..S-1 at once: attention writes its keys and
+    values, the SSM mixer its conv window and final state.  None where the
+    block's state is built one token at a time through ``decode``
+    (``rglru``, ``cross_attn``)."""
 
     def __init__(self, init, apply, cache, decode, prefill=None):
         self.init = init
@@ -354,7 +365,7 @@ REGISTRY: Dict[str, BlockDef] = {
                     lambda cfg, b, m, **kw: _attention_cache(cfg, b, m),
                     _mla_decode, _mla_prefill),
     "ssm": BlockDef(_ssm_block_init, _ssm_block_apply, _ssm_cache,
-                    _ssm_decode),
+                    _ssm_decode, _ssm_prefill),
     "rglru": BlockDef(_rglru_block_init, _rglru_block_apply, _rglru_cache,
                       _rglru_decode),
     "cross_attn": BlockDef(_cross_block_init, _cross_block_apply,

@@ -347,12 +347,13 @@ class LanguageModel:
         through position S-1).
 
         When every block can take the whole prompt at once (each kind's
-        ``BlockDef.prefill``: the attention, latent-attention and expert
-        blocks) and there is no memory, one sequence-parallel pass: each
-        layer attends over all S positions as ``forward`` does and writes
-        its S keys and values (or latents) to its cache in one store, and
+        ``BlockDef.prefill``: the attention, latent-attention, expert and
+        SSM blocks) and there is no memory, one sequence-parallel pass:
+        each layer runs over all S positions as ``forward`` does and writes
+        its cache in one store (S keys and values or latents; an SSM
+        block's conv window and its state after the chunked scan), and
         only the last position goes through the head.  Other models
-        (recurrent state, memory) run ``decode_step`` over the prompt one
+        (RG-LRU state, memory) run ``decode_step`` over the prompt one
         token at a time.
         Each trace counts its path in
         ``repro_jax_prefill_lowerings_total{path}``."""

@@ -8,7 +8,9 @@ collective bytes on train_4k).  B/C are small (2N per token) and computed
 replicated; z/x/dt shard cleanly on heads/channels.
 
 Uses the Pallas SSD kernel (TPU target) or the chunked-jnp path with
-head-block processing (XLA fallback; see kernels/ssd/ref.py)."""
+head-block processing (XLA fallback; see kernels/ssd/ref.py).  The prefill
+(``ssm_prefill``) always takes the chunked-jnp path, which can start from
+the cache's state and return the final one."""
 from __future__ import annotations
 
 from typing import Dict, Tuple
@@ -19,6 +21,11 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
 from .layers import dense_init, dtype_of, pdtype_of, rmsnorm, scope
+
+
+# the prefill's SSD vectorizes as many heads as keep one head block's
+# float32 (B, nc, L, L) tile within this many bytes (kernels/ssd/ref.py)
+PREFILL_TILE_BYTES = 64 << 20
 
 
 def _dims(cfg: ModelConfig):
@@ -61,15 +68,28 @@ def _conv_split(p, cfg: ModelConfig):
             (w[:, di + n:], b[di + n:]))
 
 
-def _causal_conv(x, w, b, *, width: int):
-    """Depthwise causal conv over seq: x (B, S, C)."""
-    pad = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+def _causal_conv(x, w, b, *, width: int, left=None):
+    """Depthwise causal conv over seq: x (B, S, C).  ``left`` (B, width-1,
+    C) is the context before position 0; None reads zeros."""
+    if left is None:
+        pad = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    else:
+        pad = jnp.concatenate([left, x], axis=1)
     s = x.shape[1]
     out = sum(pad[:, j:j + s, :] * w[j][None, None, :] for j in range(width))
     return jax.nn.silu(out + b[None, None, :])
 
 
-def ssm_apply(p, x, cfg: ModelConfig):
+def _ssm_sequence(p, x, cfg: ModelConfig, cache=None):
+    """The mixer over a whole sequence: projections, causal conv, chunked
+    SSD, D skip, gated RMSNorm, out-projection -> (out, new cache).
+
+    Without ``cache`` the sequence starts from zeros (``ssm_apply``) and the
+    new cache is None.  With ``cache`` (``init_ssm_cache``'s layout) the
+    conv reads its window as the context before position 0 and the SSD
+    starts from its state; the new cache holds the last ``conv_width - 1``
+    raw x/B/C rows and the state after the last position, as a token loop
+    of ``ssm_decode`` leaves them."""
     dt_ = dtype_of(cfg)
     di, n, h, _ = _dims(cfg)
     b, s, _ = x.shape
@@ -79,13 +99,24 @@ def ssm_apply(p, x, cfg: ModelConfig):
     cmat = x @ p["w_c"].astype(dt_)
     dt_raw = x @ p["w_dtp"].astype(dt_)
 
+    lefts = (None, None, None)
+    new_cache = None
+    if cache is not None:
+        width = cfg.conv_width
+        with scope("cache"):
+            hist = cache["conv"]
+            lefts = (hist[..., :di], hist[..., di:di + n], hist[..., di + n:])
+            raw = jnp.concatenate([v[:, -(width - 1):]
+                                   for v in (xs, bmat, cmat)], axis=-1)
+            conv = jnp.concatenate([hist, raw], axis=1)[:, -(width - 1):]
+
     (wx, bx), (wb, bb), (wc, bc) = _conv_split(p, cfg)
     xs = _causal_conv(xs, wx.astype(dt_), bx.astype(dt_),
-                      width=cfg.conv_width)
+                      width=cfg.conv_width, left=lefts[0])
     bmat = _causal_conv(bmat, wb.astype(dt_), bb.astype(dt_),
-                        width=cfg.conv_width)
+                        width=cfg.conv_width, left=lefts[1])
     cmat = _causal_conv(cmat, wc.astype(dt_), bc.astype(dt_),
-                        width=cfg.conv_width)
+                        width=cfg.conv_width, left=lefts[2])
 
     xh = xs.reshape(b, s, h, cfg.ssm_headdim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
@@ -94,9 +125,26 @@ def ssm_apply(p, x, cfg: ModelConfig):
 
     from ..distributed import sharding as shd
     from ..distributed.sharding import axis_size
-    from ..kernels.ssd import ssd_scan
     mesh = shd.active_mesh()
-    if cfg.ssd_shard_map and mesh is not None and axis_size("model") > 1:
+    # head blocks: keep the "model"-sharded head slice vectorized, loop
+    # the rest (memory ~ per-chip heads x (nc, L, L); kernels/ssd/ref.py)
+    shard = max(axis_size("model"), 1)
+    hb = max(1, h // shard)
+    if cache is not None:
+        # the chunked SSD from the cache's state, whatever the length: the
+        # chunk follows the prompt, a ragged tail is padded with dt = 0.
+        # GSPMD whatever ssd_shard_map says: that path saves the backward's
+        # all-reduces, and a prefill has no backward
+        from ..kernels.ssd.ref import ssd_chunked_from
+        chunk = min(cfg.ssm_chunk, s)
+        y, state = ssd_chunked_from(
+            cache["ssm"], xh.astype(jnp.float32), dt, p["a_log"],
+            bmat.astype(jnp.float32), cmat.astype(jnp.float32),
+            chunk=chunk, unroll_heads=cfg.attn_chunk_unroll,
+            head_blocks=_prefill_head_blocks(h, shard, b, -(-s // chunk),
+                                             chunk))
+        new_cache = {"conv": conv, "ssm": state}
+    elif cfg.ssd_shard_map and mesh is not None and axis_size("model") > 1:
         rules = shd.current_rules() or {}
         dp = rules.get("batch")
         dp_axes = (dp,) if isinstance(dp, str) else (dp or ())
@@ -105,9 +153,7 @@ def ssm_apply(p, x, cfg: ModelConfig):
             bmat.astype(jnp.float32), cmat.astype(jnp.float32), cfg,
             mesh=mesh, dp_axes=dp_axes)
     else:
-        # head blocks: keep the "model"-sharded head slice vectorized, loop
-        # the rest (memory ~ per-chip heads x (nc, L, L); kernels/ssd/ref.py)
-        hb = max(1, h // max(axis_size("model"), 1))
+        from ..kernels.ssd import ssd_scan
         y = ssd_scan(xh.astype(jnp.float32), dt, p["a_log"],
                      bmat.astype(jnp.float32), cmat.astype(jnp.float32),
                      chunk=cfg.ssm_chunk,
@@ -118,7 +164,32 @@ def ssm_apply(p, x, cfg: ModelConfig):
     y = y.reshape(b, s, di).astype(dt_)
     y = rmsnorm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
     out = y @ p["w_out"].astype(dt_)
-    return constrain(out, ("batch", "seq", "embed"))
+    return constrain(out, ("batch", "seq", "embed")), new_cache
+
+
+def _prefill_head_blocks(h: int, shard: int, b: int, nc: int,
+                         chunk: int) -> int:
+    """Head blocks for the prefill's SSD: each block the widest multiple
+    of the sharded head slice that divides ``h`` and keeps its float32
+    (b, nc, chunk, chunk) tiles within ``PREFILL_TILE_BYTES``."""
+    per_head = b * nc * chunk * chunk * 4
+    width = max([w for w in range(shard, h + 1, shard)
+                 if h % w == 0 and w * per_head <= PREFILL_TILE_BYTES],
+                default=shard)
+    return h // width
+
+
+def ssm_apply(p, x, cfg: ModelConfig):
+    """x: (B, S, D) from a zero state -> (B, S, D)."""
+    return _ssm_sequence(p, x, cfg)[0]
+
+
+def ssm_prefill(p, x, cache: Dict, cfg: ModelConfig
+                ) -> Tuple[jax.Array, Dict]:
+    """A whole prompt x (B, S, D) in one chunked pass from ``cache`` ->
+    (out (B, S, D), the cache after position S-1: conv window and SSM
+    state)."""
+    return _ssm_sequence(p, x, cfg, cache)
 
 
 def init_ssm_cache(cfg: ModelConfig, batch: int) -> Dict:

@@ -210,7 +210,8 @@ class TestSSD:
         assert bool(jnp.all(jnp.isfinite(out)))
 
     def test_ops_fallback(self):
-        """Non-divisible seq routes to the exact scan."""
+        """A seq that is not a multiple of the chunk takes the chunked jnp
+        path, its tail padded with dt = 0: the exact scan's outputs."""
         b, s, h, p, n = 1, 100, 1, 8, 16
         ks = jax.random.split(KEY, 5)
         x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32)

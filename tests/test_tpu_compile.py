@@ -23,6 +23,7 @@ from repro.kernels.matmul import kernel as mm_kernel
 from repro.kernels.rmsnorm import kernel as rms_kernel
 from repro.kernels.ssd import kernel as ssd_kernel
 from repro.models import build
+from repro.obs import metrics
 
 V5E_HBM_BYTES = 16e9
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -131,18 +132,24 @@ def test_danube_prefill_fits_one_v5e(one_chip):
     assert total < V5E_HBM_BYTES, total
 
 
-def _dsv3_cell_model():
-    """The chip benchmark's cut DeepSeek-V3: 3 dense and 4 expert layers,
-    8 of 256 experts held, a quarter of the vocabulary, published widths."""
+def _cell_model(name):
+    """A model of the chip benchmark's configuration ``name`` as its file
+    states it."""
     import json
     from pathlib import Path
 
     from repro.configs.base import ModelConfig
 
     path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
-            / "configs" / "deepseek-v3-671b.json")
+            / "configs" / f"{name}.json")
     m = json.loads(path.read_text())["model"]
     return build(ModelConfig(**dict(m, pattern=tuple(m["pattern"]))))
+
+
+def _dsv3_cell_model():
+    """The chip benchmark's cut DeepSeek-V3: 3 dense and 4 expert layers,
+    8 of 256 experts held, a quarter of the vocabulary, published widths."""
+    return _cell_model("deepseek-v3-671b")
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode_step"])
@@ -170,4 +177,36 @@ def test_dsv3_cell_fits_one_v5e(one_chip, program):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 9.1e9      # 4,555 M bf16 params
+    assert total < 15.75e9, total
+
+
+@pytest.mark.parametrize("batch, prompt, cache_len", [
+    (12, 128, 640),      # the mamba2 cell: one chunk of 128
+    (4, 4096, 4112),     # a long prompt: 16 chunks of 256
+])
+def test_mamba2_prefill_fits_one_v5e(one_chip, batch, prompt, cache_len):
+    """mamba2's prefill as the benchmark's file states the model: one
+    chunked pass over the prompt (no token loop), the SSD tiles and state
+    in float32, beside 2.7 GB of weights within one chip's HBM."""
+    model = _cell_model("mamba2-1.3b")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(model.init_cache(batch, cache_len, abstract=True))
+    tokens = jax.ShapeDtypeStruct((batch, prompt), jnp.int32,
+                                  sharding=one_chip)
+    series = metrics.snapshot()["repro_jax_prefill_lowerings_total"]
+    before = {s["labels"]["path"]: s["value"] for s in series["series"]}
+    compiled = jax.jit(model.prefill).lower(params, tokens, cache).compile()
+    series = metrics.snapshot()["repro_jax_prefill_lowerings_total"]
+    after = {s["labels"]["path"]: s["value"] for s in series["series"]}
+    assert after["sequence"] == before["sequence"] + 1
+    assert after["token"] == before["token"]
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 2.6e9      # 1.3B bf16 params
     assert total < 15.75e9, total
