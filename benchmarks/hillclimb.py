@@ -48,24 +48,14 @@ EXPERIMENTS = {
                      "microbatches": 16, "accum_dtype": "bfloat16"},
     },
     # ---------------- deepseek-v3-671b x train_4k (worst fraction) -------
-    "dsv3_mtp_share": {
-        "cell": ("deepseek-v3-671b", "train_4k"),
-        "hypothesis": (
-            "MTP head re-runs the full 61-layer trunk forward: one extra "
-            "fwd = +~33% flops at remat=full (fwd:bwd = 1:2). Sharing the "
-            "trunk removes it. Napkin: HLO flops x~0.75, useful 0.25 -> "
-            "~0.33; memory term down similarly."),
-        "override": {"cfg_overrides": {"mtp_share_trunk": True}},
-    },
     "dsv3_mtp_remat_block": {
         "cell": ("deepseek-v3-671b", "train_4k"),
         "hypothesis": (
             "remat=full recomputes the whole block in bwd (5/3 flop "
             "factor); with d_model sharded over 'model', block-level remat "
-            "(4/3) fits. Napkin: flops x0.8 on top of MTP sharing; "
-            "useful -> ~0.42."),
-        "override": {"cfg_overrides": {"mtp_share_trunk": True},
-                     "remat": "block"},
+            "(4/3) fits. Napkin: flops x0.8 (the MTP head reads the "
+            "main trunk's hidden states); useful -> ~0.42."),
+        "override": {"remat": "block"},
     },
     "dsv3_full_stack": {
         "cell": ("deepseek-v3-671b", "train_4k"),
@@ -75,8 +65,7 @@ EXPERIMENTS = {
             "15.8 GB/chip -> ~10.6 GB/chip => the cell finally FITS "
             "single-pod HBM (the baseline's blocker). Terms roughly "
             "unchanged; memory_analysis is the metric."),
-        "override": {"cfg_overrides": {"mtp_share_trunk": True},
-                     "remat": "block", "moment_dtype": "int8"},
+        "override": {"remat": "block", "moment_dtype": "int8"},
     },
     # ---------------- llama3-405b x train_4k (paper-representative) ------
     "llama405b_remat_block": {

@@ -97,17 +97,16 @@ class ModelConfig:
     scan_layers: bool = True
     residual_scale: float = 1.0    # minicpm-style depth scaling
     logit_scale: float = 1.0
-    use_flash_kernel: bool = False  # Pallas path (TPU); CPU tests use XLA
     attn_chunk: int = 0            # >0: chunked (flash-in-XLA) attention
     attn_chunk_unroll: bool = False  # unroll the chunk loop (dry-run
                                      # accounting: while-bodies are counted
                                      # once by cost_analysis)
     # --- §Perf hillclimb switches (off = paper-faithful baseline) ---------
-    ssd_shard_map: bool = False    # explicit shard_map SSD layer (kills the
-                                   # GSPMD bwd all-reduces; EXPERIMENTS §Perf)
+    ssd_shard_map: bool = False    # explicit shard_map SSD layer: one psum
+                                   # per replicated input's gradient in
+                                   # place of GSPMD's per-head-block bwd
+                                   # all-reduces (models/ssm.py)
     ssd_tile_bf16: bool = False    # bf16 (L,L) SSD tiles, fp32 accumulation
-    mtp_share_trunk: bool = False  # MTP head reuses the main forward's
-                                   # hidden states instead of re-running it
 
     def __post_init__(self):
         if self.head_dim == 0:

@@ -1,6 +1,5 @@
 """Attention variants: GQA (full / sliding-window / chunked), decode-with-
-cache, and cross-attention.  Sharding-friendly einsum formulation with
-optional Pallas flash kernel."""
+cache, and cross-attention.  Sharding-friendly einsum formulation."""
 from __future__ import annotations
 
 import math
@@ -123,21 +122,16 @@ def _project_qkv(p, x, cfg: ModelConfig, *, positions=None,
     return q, k, v
 
 
-def _attend(p, q, k, v, cfg: ModelConfig, *, causal: bool, window: int,
-            self_attn: bool):
-    """Attention of q over k, v and the output projection: (B, S, D)."""
+def _attend(p, q, k, v, cfg: ModelConfig, *, causal: bool, window: int):
+    """Attention of q over k, v and the output projection: (B, S, D).
+    Query chunks of ``cfg.attn_chunk`` when it divides a longer sequence,
+    else all the scores at once."""
     dt = dtype_of(cfg)
     b, s = q.shape[:2]
     hd = cfg.head_dim
     scale = hd ** -0.5
 
-    if cfg.use_flash_kernel and self_attn and s % 128 == 0:
-        from ..kernels.flash_attention import flash_attention
-        o = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                            v.transpose(0, 2, 1, 3), sm_scale=scale,
-                            causal=causal, window=window)
-        o = o.transpose(0, 2, 1, 3)
-    elif cfg.attn_chunk > 0 and s > cfg.attn_chunk and s % cfg.attn_chunk == 0:
+    if cfg.attn_chunk > 0 and s > cfg.attn_chunk and s % cfg.attn_chunk == 0:
         o = _sdpa_chunked(q, k, v, scale=scale, causal=causal, window=window,
                           logit_cap=cfg.attn_logit_softcap,
                           chunk=cfg.attn_chunk,
@@ -157,8 +151,7 @@ def attn_apply(p, x, cfg: ModelConfig, *, positions=None,
     cross-attention (k_in, v_in already projected inputs)."""
     q, k, v = _project_qkv(p, x, cfg, positions=positions,
                            kv_override=kv_override)
-    return _attend(p, q, k, v, cfg, causal=causal, window=window,
-                   self_attn=kv_override is None)
+    return _attend(p, q, k, v, cfg, causal=causal, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -204,8 +197,7 @@ def prefill_attn_apply(p, x, cache: Dict, cfg: ModelConfig, *,
     ``attn_apply`` computes it, with the keys and values written to the
     cache; returns (out, new_cache)."""
     q, k, v = _project_qkv(p, x, cfg)
-    out = _attend(p, q, k, v, cfg, causal=True, window=window,
-                  self_attn=True)
+    out = _attend(p, q, k, v, cfg, causal=True, window=window)
     return out, write_kv_prompt(cache, k, v, window=window)
 
 

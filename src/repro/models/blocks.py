@@ -1,20 +1,26 @@
-"""Block registry: per-kind (init, apply, cache_init/spec, decode).
+"""Block registry: each block kind is a sequence mixer plus an FFN, run by
+one body in all three modes (``apply``, ``prefill``, ``decode``).
 
-Every block owns its norms and residual adds, each under the scope of
-the block part it feeds (``layers.SCOPES``).  Kinds:
-  attn        full causal GQA attention + SwiGLU MLP
-  local_attn  sliding-window GQA attention + MLP
-  moe         (MLA or GQA) attention + MoE FFN (returns aux loss)
-  mla         MLA attention + SwiGLU MLP (DeepSeek-V3's leading dense
-              layers, width d_ff)
-  ssm         Mamba2 mixer (no MLP; the block IS the mixer)
+The body: under the mixer's scope (``layers.SCOPES``), norm, mixer and
+residual; for ``cross_attn``, a gated cross-attention residual of the
+memory under ``cross``; then the FFN's residual under the FFN's scope.
+Mixers: ``gqa`` (full, sliding-window or bidirectional attention),
+``MLA`` (latent attention), ``SSM`` (Mamba2) and ``RGLRU``.  FFNs: the
+dense SwiGLU ``MLP`` (present iff the params hold ``mlp``), ``MOE``
+(which returns the aux loss), or none.  Kinds:
+  attn        causal GQA + MLP
+  local_attn  sliding-window GQA + MLP
+  moe         the model's attention (MLA or GQA) + MoE
+  mla         MLA + MLP (DeepSeek-V3's leading dense layers, width d_ff)
+  ssm         Mamba2 mixer, no FFN
   rglru       RG-LRU recurrent mixer + MLP
-  cross_attn  self-attn + cross-attn(memory) + MLP (whisper dec / vlm)
-  enc_attn    bidirectional attention + MLP (whisper encoder)
+  cross_attn  causal GQA + gated cross-attention of the memory + MLP
+              (whisper decoder / vlm)
+  enc_attn    bidirectional GQA + MLP, no cache (whisper encoder)
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +31,70 @@ from . import mla as mla_mod
 from . import moe as moe_mod
 from . import rglru as rglru_mod
 from . import ssm as ssm_mod
-from .layers import dtype_of, mlp_apply, mlp_init, rmsnorm, scope
+from .layers import mlp_apply, mlp_init, rmsnorm, scope
+
+
+class Mixer(NamedTuple):
+    """A block's sequence mixer: its key in the block's params and in its
+    cache, the scope its norm, ops and residual run under, and
+
+    init(key, cfg) -> params
+    apply(p, h, cfg) -> out, a whole sequence from an empty state
+    cache(cfg, batch, max_len) -> its decode state
+    decode(p, h, cache, pos, cfg) -> (out, cache), one token at ``pos``
+    prefill(p, h, cache, cfg) -> (out, cache), a whole prompt at positions
+        0..S-1; None where the state is built one token at a time."""
+    param: str
+    cache_key: str
+    scope: str
+    init: Callable
+    apply: Callable
+    cache: Callable
+    decode: Callable
+    prefill: Optional[Callable]
+
+
+def gqa(local: bool = False, causal: bool = True) -> Mixer:
+    """Grouped-query attention: over the last ``cfg.window`` positions
+    when ``local``, bidirectional unless ``causal``."""
+    def window(cfg):
+        return cfg.window if local else 0
+
+    return Mixer(
+        "attn", "kv", "attn", attn_mod.attn_init,
+        lambda p, h, cfg: attn_mod.attn_apply(p, h, cfg, causal=causal,
+                                              window=window(cfg)),
+        lambda cfg, b, m: attn_mod.init_kv_cache(cfg, b, m,
+                                                 window=window(cfg)),
+        lambda p, h, c, pos, cfg: attn_mod.decode_attn_apply(
+            p, h, c, pos, cfg, window=window(cfg)),
+        lambda p, h, c, cfg: attn_mod.prefill_attn_apply(
+            p, h, c, cfg, window=window(cfg)))
+
+
+GQA = gqa()
+MLA = Mixer("attn", "mla", "attn", mla_mod.mla_init, mla_mod.mla_apply,
+            mla_mod.init_mla_cache, mla_mod.mla_decode, mla_mod.mla_prefill)
+SSM = Mixer("ssm", "ssm", "ssm", ssm_mod.ssm_init, ssm_mod.ssm_apply,
+            lambda cfg, b, m: ssm_mod.init_ssm_cache(cfg, b),
+            ssm_mod.ssm_decode, ssm_mod.ssm_prefill)
+RGLRU = Mixer("lru", "lru", "rglru", rglru_mod.rglru_init,
+              rglru_mod.rglru_apply,
+              lambda cfg, b, m: rglru_mod.init_rglru_cache(cfg, b),
+              rglru_mod.rglru_decode, None)
+
+
+class FFN(NamedTuple):
+    """A block's feed-forward part: its key in the block's params (and its
+    scope), ``init(key, cfg)`` and ``apply(p, h, cfg) -> (out, aux loss
+    or None)``."""
+    param: str
+    init: Callable
+    apply: Callable
+
+
+MLP = FFN("mlp", mlp_init, lambda p, h, cfg: (mlp_apply(p, h, cfg), None))
+MOE = FFN("moe", moe_mod.moe_init, moe_mod.moe_apply)
 
 
 def _no_aux():
@@ -38,255 +107,6 @@ def _norm_init(cfg):
     return jnp.ones((cfg.d_model,), jnp.dtype(cfg.param_dtype))
 
 
-@scope("mlp")
-def _mlp_residual(p, x, cfg: ModelConfig):
-    """x + MLP(norm(x))."""
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    return x + mlp_apply(p["mlp"], h, cfg)
-
-
-# --------------------------------------------------------------------------
-# attn / local_attn
-# --------------------------------------------------------------------------
-
-def _attn_block_init(key, cfg: ModelConfig):
-    k1, k2 = jax.random.split(key)
-    p = {"ln1": _norm_init(cfg), "attn": attn_mod.attn_init(k1, cfg)}
-    if cfg.d_ff > 0:
-        p["ln2"] = _norm_init(cfg)
-        p["mlp"] = mlp_init(k2, cfg)
-    return p
-
-
-def _attn_block_apply(p, x, cfg: ModelConfig, *, window=0, causal=True,
-                      memory=None):
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=causal,
-                                    window=window)
-    if "mlp" in p:
-        x = _mlp_residual(p, x, cfg)
-    return x, _no_aux()
-
-
-def _attn_cache(cfg, batch, max_len, *, window=0):
-    return {"kv": attn_mod.init_kv_cache(cfg, batch, max_len, window=window)}
-
-
-def _attn_decode(p, x, cache, pos, cfg: ModelConfig, *, window=0,
-                 memory=None):
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        o, kv = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"], pos,
-                                           cfg, window=window)
-        x = x + o
-    if "mlp" in p:
-        x = _mlp_residual(p, x, cfg)
-    return x, {"kv": kv}
-
-
-def _attn_prefill(p, x, cache, cfg: ModelConfig, *, window=0):
-    """The whole prompt through the block, its keys and values into the
-    cache."""
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        o, kv = attn_mod.prefill_attn_apply(p["attn"], h, cache["kv"], cfg,
-                                            window=window)
-        x = x + o
-    if "mlp" in p:
-        x = _mlp_residual(p, x, cfg)
-    return x, {"kv": kv}
-
-
-# --------------------------------------------------------------------------
-# moe (attention = MLA or GQA, FFN = MoE)
-# --------------------------------------------------------------------------
-
-def _attention_init(key, cfg: ModelConfig):
-    return (mla_mod.mla_init(key, cfg) if cfg.use_mla
-            else attn_mod.attn_init(key, cfg))
-
-
-def _attention_apply(p, x, cfg: ModelConfig):
-    """x + the block's causal self-attention (MLA or GQA)."""
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if cfg.use_mla:
-            return x + mla_mod.mla_apply(p["attn"], h, cfg)
-        return x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
-
-
-def _attention_cache(cfg, batch, max_len):
-    if cfg.use_mla:
-        return {"mla": mla_mod.init_mla_cache(cfg, batch, max_len)}
-    return {"kv": attn_mod.init_kv_cache(cfg, batch, max_len)}
-
-
-def _attention_decode(p, x, cache, pos, cfg: ModelConfig):
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if cfg.use_mla:
-            o, new = mla_mod.mla_decode(p["attn"], h, cache["mla"], pos,
-                                        cfg)
-            new_cache = {"mla": new}
-        else:
-            o, new = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"],
-                                                pos, cfg)
-            new_cache = {"kv": new}
-        return x + o, new_cache
-
-
-def _attention_prefill(p, x, cache, cfg: ModelConfig):
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if cfg.use_mla:
-            o, new = mla_mod.mla_prefill(p["attn"], h, cache["mla"], cfg)
-            new_cache = {"mla": new}
-        else:
-            o, new = attn_mod.prefill_attn_apply(p["attn"], h, cache["kv"],
-                                                 cfg)
-            new_cache = {"kv": new}
-        return x + o, new_cache
-
-
-def _moe_block_init(key, cfg: ModelConfig):
-    k1, k2 = jax.random.split(key)
-    return {"ln1": _norm_init(cfg), "attn": _attention_init(k1, cfg),
-            "ln2": _norm_init(cfg), "moe": moe_mod.moe_init(k2, cfg)}
-
-
-@scope("moe")
-def _moe_residual(p, x, cfg: ModelConfig):
-    """(x + MoE(norm(x)), aux loss)."""
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    y, aux = moe_mod.moe_apply(p["moe"], h, cfg)
-    return x + y, aux
-
-
-def _moe_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    return _moe_residual(p, _attention_apply(p, x, cfg), cfg)
-
-
-def _moe_cache(cfg, batch, max_len, **kw):
-    return _attention_cache(cfg, batch, max_len)
-
-
-def _moe_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    x, new_cache = _attention_decode(p, x, cache, pos, cfg)
-    return _moe_residual(p, x, cfg)[0], new_cache
-
-
-def _moe_prefill(p, x, cache, cfg: ModelConfig):
-    x, new_cache = _attention_prefill(p, x, cache, cfg)
-    return _moe_residual(p, x, cfg)[0], new_cache
-
-
-# --------------------------------------------------------------------------
-# mla (MLA attention + dense SwiGLU: the leading dense layers)
-# --------------------------------------------------------------------------
-
-def _mla_block_init(key, cfg: ModelConfig):
-    k1, k2 = jax.random.split(key)
-    return {"ln1": _norm_init(cfg), "attn": mla_mod.mla_init(k1, cfg),
-            "ln2": _norm_init(cfg), "mlp": mlp_init(k2, cfg)}
-
-
-def _mla_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    return _mlp_residual(p, _attention_apply(p, x, cfg), cfg), _no_aux()
-
-
-def _mla_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    x, new_cache = _attention_decode(p, x, cache, pos, cfg)
-    return _mlp_residual(p, x, cfg), new_cache
-
-
-def _mla_prefill(p, x, cache, cfg: ModelConfig):
-    x, new_cache = _attention_prefill(p, x, cache, cfg)
-    return _mlp_residual(p, x, cfg), new_cache
-
-
-# --------------------------------------------------------------------------
-# ssm
-# --------------------------------------------------------------------------
-
-def _ssm_block_init(key, cfg: ModelConfig):
-    return {"ln1": _norm_init(cfg), "ssm": ssm_mod.ssm_init(key, cfg)}
-
-
-@scope("ssm")
-def _ssm_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    return x + ssm_mod.ssm_apply(p["ssm"], h, cfg), _no_aux()
-
-
-def _ssm_cache(cfg, batch, max_len, **kw):
-    return {"ssm": ssm_mod.init_ssm_cache(cfg, batch)}
-
-
-@scope("ssm")
-def _ssm_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    o, new = ssm_mod.ssm_decode(p["ssm"], h, cache["ssm"], pos, cfg)
-    return x + o, {"ssm": new}
-
-
-@scope("ssm")
-def _ssm_prefill(p, x, cache, cfg: ModelConfig):
-    """The whole prompt through the mixer in one chunked pass, its conv
-    window and final state into the cache."""
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    o, new = ssm_mod.ssm_prefill(p["ssm"], h, cache["ssm"], cfg)
-    return x + o, {"ssm": new}
-
-
-# --------------------------------------------------------------------------
-# rglru
-# --------------------------------------------------------------------------
-
-def _rglru_block_init(key, cfg: ModelConfig):
-    k1, k2 = jax.random.split(key)
-    p = {"ln1": _norm_init(cfg), "lru": rglru_mod.rglru_init(k1, cfg)}
-    if cfg.d_ff > 0:
-        p["ln2"] = _norm_init(cfg)
-        p["mlp"] = mlp_init(k2, cfg)
-    return p
-
-
-def _rglru_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    with scope("rglru"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        x = x + rglru_mod.rglru_apply(p["lru"], h, cfg)
-    if "mlp" in p:
-        x = _mlp_residual(p, x, cfg)
-    return x, _no_aux()
-
-
-def _rglru_cache(cfg, batch, max_len, **kw):
-    return {"lru": rglru_mod.init_rglru_cache(cfg, batch)}
-
-
-def _rglru_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    with scope("rglru"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        o, new = rglru_mod.rglru_decode(p["lru"], h, cache["lru"], pos, cfg)
-        x = x + o
-    if "mlp" in p:
-        x = _mlp_residual(p, x, cfg)
-    return x, {"lru": new}
-
-
-# --------------------------------------------------------------------------
-# cross_attn (self + cross + mlp) and enc_attn (bidirectional + mlp)
-# --------------------------------------------------------------------------
-
-def _cross_block_init(key, cfg: ModelConfig):
-    k1, k2, k3 = jax.random.split(key, 3)
-    return {"ln1": _norm_init(cfg), "attn": attn_mod.attn_init(k1, cfg),
-            "lnx": _norm_init(cfg), "xattn": attn_mod.attn_init(k2, cfg),
-            "ln2": _norm_init(cfg), "mlp": mlp_init(k3, cfg),
-            "xgate": jnp.zeros((), jnp.dtype(cfg.param_dtype))}
-
-
 @scope("cross")
 def _cross_residual(p, x, memory, cfg: ModelConfig):
     """x + gate * cross-attention(norm(x), memory)."""
@@ -296,81 +116,95 @@ def _cross_residual(p, x, memory, cfg: ModelConfig):
     return x + jnp.tanh(p["xgate"]).astype(x.dtype) * xo
 
 
-def _cross_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    assert memory is not None, "cross_attn block needs memory"
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        x = x + attn_mod.attn_apply(p["attn"], h, cfg, causal=True)
-    x = _cross_residual(p, x, memory, cfg)
-    return _mlp_residual(p, x, cfg), _no_aux()
-
-
-def _cross_cache(cfg, batch, max_len, **kw):
-    return {"kv": attn_mod.init_kv_cache(cfg, batch, max_len)}
-
-
-def _cross_decode(p, x, cache, pos, cfg: ModelConfig, *, memory=None):
-    with scope("attn"):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        o, kv = attn_mod.decode_attn_apply(p["attn"], h, cache["kv"], pos,
-                                           cfg)
-        x = x + o
-    x = _cross_residual(p, x, memory, cfg)
-    return _mlp_residual(p, x, cfg), {"kv": kv}
-
-
-def _enc_block_apply(p, x, cfg: ModelConfig, *, memory=None):
-    return _attn_block_apply(p, x, cfg, causal=False)
-
-
-# --------------------------------------------------------------------------
-# registry
-# --------------------------------------------------------------------------
-
 class BlockDef:
-    """``prefill(p, x, cache, cfg) -> (x, new_cache)`` takes a whole
-    prompt at positions 0..S-1 at once: attention writes its keys and
-    values, the SSM mixer its conv window and final state.  None where the
-    block's state is built one token at a time through ``decode``
-    (``rglru``, ``cross_attn``)."""
+    """One block kind.  ``mixer`` is None for the model's own attention:
+    MLA when ``cfg.use_mla``, else causal GQA.
 
-    def __init__(self, init, apply, cache, decode, prefill=None):
-        self.init = init
-        self.apply = apply
-        self.cache = cache
-        self.decode = decode
-        self.prefill = prefill
+    ``apply(p, x, cfg, memory=None) -> (x, aux loss)``;
+    ``cache(cfg, batch, max_len)``;
+    ``decode(p, x, cache, pos, cfg, memory=None) -> (x, new_cache)``;
+    ``prefill(p, x, cache, cfg) -> (x, new_cache)`` takes a whole prompt
+    at positions 0..S-1 at once: attention writes its keys and values,
+    the SSM mixer its conv window and final state.  ``prefill`` is None
+    where the block's state is built one token at a time through
+    ``decode`` (``rglru``, ``cross_attn``), and both are None for a block
+    without a cache (``enc_attn``)."""
+
+    def __init__(self, mixer: Optional[Mixer], ffn: Optional[FFN], *,
+                 cross: bool = False, cached: bool = True):
+        self._mixer, self.ffn, self.cross = mixer, ffn, cross
+        self.decode = self._decode if cached else None
+        sequence = mixer is None or mixer.prefill is not None
+        self.prefill = (self._prefill if cached and sequence and not cross
+                        else None)
+
+    def mixer(self, cfg: ModelConfig) -> Mixer:
+        if self._mixer is not None:
+            return self._mixer
+        return MLA if cfg.use_mla else GQA
+
+    def init(self, key, cfg: ModelConfig) -> Dict:
+        mixer = self.mixer(cfg)
+        if self.ffn is None:
+            return {"ln1": _norm_init(cfg), mixer.param: mixer.init(key, cfg)}
+        keys = jax.random.split(key, 3 if self.cross else 2)
+        p = {"ln1": _norm_init(cfg), mixer.param: mixer.init(keys[0], cfg)}
+        if self.cross:
+            p.update(lnx=_norm_init(cfg),
+                     xattn=attn_mod.attn_init(keys[1], cfg),
+                     xgate=jnp.zeros((), jnp.dtype(cfg.param_dtype)))
+        if self.ffn is MOE or cfg.d_ff > 0:
+            p.update(ln2=_norm_init(cfg),
+                     **{self.ffn.param: self.ffn.init(keys[-1], cfg)})
+        return p
+
+    def _body(self, p, x, cfg: ModelConfig, memory, run):
+        """x through the block, ``run(mixer, its params, h) -> (out,
+        state)`` running the mixer -> (x, the mixer's new cache, the FFN's
+        aux loss or None)."""
+        mixer = self.mixer(cfg)
+        with scope(mixer.scope):
+            h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+            out, state = run(mixer, p[mixer.param], h)
+            x = x + out
+        if self.cross:
+            assert memory is not None, "cross_attn block needs memory"
+            x = _cross_residual(p, x, memory, cfg)
+        aux = None
+        if self.ffn is not None and self.ffn.param in p:
+            with scope(self.ffn.param):
+                h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+                out, aux = self.ffn.apply(p[self.ffn.param], h, cfg)
+                x = x + out
+        return x, {mixer.cache_key: state}, aux
+
+    def apply(self, p, x, cfg: ModelConfig, memory=None):
+        x, _, aux = self._body(p, x, cfg, memory, lambda m, mp, h: (
+            m.apply(mp, h, cfg), None))
+        return x, _no_aux() if aux is None else aux
+
+    def cache(self, cfg: ModelConfig, batch: int, max_len: int) -> Dict:
+        mixer = self.mixer(cfg)
+        return {mixer.cache_key: mixer.cache(cfg, batch, max_len)}
+
+    def _decode(self, p, x, cache, pos, cfg: ModelConfig, memory=None):
+        x, new, _ = self._body(p, x, cfg, memory, lambda m, mp, h: m.decode(
+            mp, h, cache[m.cache_key], pos, cfg))
+        return x, new
+
+    def _prefill(self, p, x, cache, cfg: ModelConfig):
+        x, new, _ = self._body(p, x, cfg, None, lambda m, mp, h: (
+            m.prefill(mp, h, cache[m.cache_key], cfg)))
+        return x, new
 
 
 REGISTRY: Dict[str, BlockDef] = {
-    "attn": BlockDef(
-        _attn_block_init,
-        lambda p, x, cfg, **kw: _attn_block_apply(p, x, cfg, window=0, **kw),
-        lambda cfg, b, m, **kw: _attn_cache(cfg, b, m, window=0),
-        lambda p, x, c, pos, cfg, **kw: _attn_decode(p, x, c, pos, cfg,
-                                                     window=0, **kw),
-        lambda p, x, c, cfg: _attn_prefill(p, x, c, cfg, window=0)),
-    "local_attn": BlockDef(
-        _attn_block_init,
-        lambda p, x, cfg, **kw: _attn_block_apply(
-            p, x, cfg, window=cfg.window, **kw),
-        lambda cfg, b, m, **kw: _attn_cache(cfg, b, m, window=cfg.window),
-        lambda p, x, c, pos, cfg, **kw: _attn_decode(
-            p, x, c, pos, cfg, window=cfg.window, **kw),
-        lambda p, x, c, cfg: _attn_prefill(p, x, c, cfg,
-                                           window=cfg.window)),
-    "moe": BlockDef(_moe_block_init, _moe_block_apply, _moe_cache,
-                    _moe_decode, _moe_prefill),
-    "mla": BlockDef(_mla_block_init, _mla_block_apply,
-                    lambda cfg, b, m, **kw: _attention_cache(cfg, b, m),
-                    _mla_decode, _mla_prefill),
-    "ssm": BlockDef(_ssm_block_init, _ssm_block_apply, _ssm_cache,
-                    _ssm_decode, _ssm_prefill),
-    "rglru": BlockDef(_rglru_block_init, _rglru_block_apply, _rglru_cache,
-                      _rglru_decode),
-    "cross_attn": BlockDef(_cross_block_init, _cross_block_apply,
-                           _cross_cache, _cross_decode),
-    "enc_attn": BlockDef(_attn_block_init, _enc_block_apply,
-                         lambda cfg, b, m, **kw: {},
-                         None),
+    "attn": BlockDef(GQA, MLP),
+    "local_attn": BlockDef(gqa(local=True), MLP),
+    "moe": BlockDef(None, MOE),
+    "mla": BlockDef(MLA, MLP),
+    "ssm": BlockDef(SSM, None),
+    "rglru": BlockDef(RGLRU, MLP),
+    "cross_attn": BlockDef(GQA, MLP, cross=True),
+    "enc_attn": BlockDef(gqa(causal=False), MLP, cached=False),
 }

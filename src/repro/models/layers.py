@@ -49,10 +49,7 @@ def embed_init(key, vocab: int, d: int, dtype):
     return (jax.random.normal(key, (vocab, d)) * 0.02).astype(dtype)
 
 
-def rmsnorm(x, scale, eps: float = 1e-6, *, use_kernel: bool = False):
-    if use_kernel:
-        from ..kernels.rmsnorm import rmsnorm as k_rmsnorm
-        return k_rmsnorm(x, scale, eps=eps)
+def rmsnorm(x, scale, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)

@@ -1,14 +1,16 @@
 """Unified language model: pattern-scanned decoder (+ optional encoder /
 modality memory), with train forward, loss, prefill and one-token decode.
 
-Layers are stored STACKED (leading dim = n_groups) and executed with
+Layers are stored STACKED (leading dim = layer count) and executed with
 jax.lax.scan so compile time is independent of depth; remat policy wraps
-the per-group apply.  Device ops carry the names of ``layers.SCOPES``.
+the per-group apply.  Each stack (``Stack``: the leading dense
+``prefix``, the ``pattern`` ``groups``, the encoder) runs through one
+walk, ``LanguageModel._walk``.  Device ops carry the names of
+``layers.SCOPES``.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,41 +35,87 @@ for _path in PREFILL_PATHS:
     _prefill_lowerings(_path)
 
 
+class Stack(NamedTuple):
+    """``count`` layers stacked on a leading axis, each the block ``kinds``
+    in order, run under ``cfg``.  A ``grouped`` layer (the pattern group)
+    keys each block's params and cache ``b{i}``; otherwise a layer is its
+    one block's own.  ``remat`` wraps each layer's ``forward`` in
+    ``cfg.remat``'s checkpoint policy."""
+    kinds: Tuple[str, ...]
+    cfg: ModelConfig
+    count: int
+    grouped: bool = False
+    remat: bool = False
+
+    def blocks(self):
+        return [(f"b{i}", REGISTRY[k]) for i, k in enumerate(self.kinds)]
+
+    def part(self, layer, key: str):
+        """One block's part of a layer's params or cache."""
+        return layer[key] if self.grouped else layer
+
+    def layer(self, parts: Dict[str, Any]):
+        """A layer's tree from its blocks' parts."""
+        return parts if self.grouped else parts["b0"]
+
+    def init(self, key):
+        """One layer's params."""
+        keys = (jax.random.split(key, len(self.kinds)) if self.grouped
+                else (key,))
+        return self.layer({b: block.init(k, self.cfg) for (b, block), k
+                           in zip(self.blocks(), keys)})
+
+    def cache(self, batch: int, max_len: int):
+        """One layer's decode cache."""
+        return self.layer({b: block.cache(self.cfg, batch, max_len)
+                           for b, block in self.blocks()})
+
+
 class LanguageModel:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
 
-    # ------------------------------------------------------------------ init
-    def _group_init(self, key):
+    def _stacks(self) -> Dict[str, Stack]:
+        """The decoder's stacks by params and cache key, in the order they
+        run: the ``first_dense`` leading layers of ``prefix_kind`` (no
+        remat), then the ``n_groups`` pattern groups."""
         cfg = self.cfg
-        keys = jax.random.split(key, len(cfg.pattern))
-        return {f"b{i}": REGISTRY[kind].init(keys[i], cfg)
-                for i, kind in enumerate(cfg.pattern)}
+        stacks = {}
+        if cfg.first_dense > 0:
+            stacks["prefix"] = Stack(
+                (cfg.prefix_kind,), cfg.replace(pattern=(cfg.prefix_kind,)),
+                cfg.first_dense)
+        stacks["groups"] = Stack(cfg.pattern, cfg, cfg.n_groups,
+                                 grouped=True, remat=True)
+        return stacks
 
+    def _encoder(self) -> Stack:
+        return Stack(("enc_attn",), self.cfg, self.cfg.enc_layers)
+
+    # ------------------------------------------------------------------ init
     def init(self, key) -> Dict:
         cfg = self.cfg
         k_embed, k_groups, k_head, k_enc, k_mtp, k_pre = \
             jax.random.split(key, 6)
         pd = pdtype_of(cfg)
+
+        def stacked(stack, key):
+            return jax.vmap(stack.init)(jax.random.split(key, stack.count))
+
         params: Dict[str, Any] = {
             "tok_embed": embed_init(k_embed, cfg.vocab, cfg.d_model, pd),
             "final_norm": jnp.ones((cfg.d_model,), pd),
         }
-        group_keys = jax.random.split(k_groups, cfg.n_groups)
-        params["groups"] = jax.vmap(self._group_init)(group_keys)
-        if cfg.first_dense > 0:
-            pre_keys = jax.random.split(k_pre, cfg.first_dense)
-            params["prefix"] = jax.vmap(
-                lambda k: REGISTRY[cfg.prefix_kind].init(
-                    k, self._prefix_cfg()))(pre_keys)
+        stacks = self._stacks()
+        params["groups"] = stacked(stacks["groups"], k_groups)
+        if "prefix" in stacks:
+            params["prefix"] = stacked(stacks["prefix"], k_pre)
         if not cfg.tie_embeddings:
             params["lm_head"] = (jax.random.normal(
                 k_head, (cfg.d_model, cfg.vocab)) * 0.02).astype(pd)
         if cfg.enc_layers > 0:
-            enc_keys = jax.random.split(k_enc, cfg.enc_layers)
             params["encoder"] = {
-                "blocks": jax.vmap(
-                    lambda k: REGISTRY["enc_attn"].init(k, cfg))(enc_keys),
+                "blocks": stacked(self._encoder(), k_enc),
                 "final_norm": jnp.ones((cfg.d_model,), pd),
             }
         if cfg.mtp_depth > 0:
@@ -83,9 +131,62 @@ class LanguageModel:
             }
         return params
 
-    def _prefix_cfg(self) -> ModelConfig:
-        """The config the ``first_dense`` leading layers run under."""
-        return self.cfg.replace(pattern=(self.cfg.prefix_kind,))
+    # ----------------------------------------------------------- layer walk
+    def _walk(self, body: Callable, carry, xs):
+        """``body(carry, one layer's slice of xs) -> (carry, y)`` over the
+        layers stacked in ``xs``: scanned, or unrolled when not
+        ``cfg.scan_layers``; returns the carry and the ys stacked."""
+        if self.cfg.scan_layers:
+            return jax.lax.scan(body, carry, xs)
+        ys = []
+        for i in range(jax.tree.leaves(xs)[0].shape[0]):
+            carry, y = body(carry, jax.tree.map(lambda a: a[i], xs))
+            ys.append(y)
+        return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+    def _run(self, stack: Stack, params, x, memory=None):
+        """x through the stack's layers as ``forward`` runs them -> (x, the
+        blocks' aux loss summed)."""
+        cfg = self.cfg
+
+        def layer(lp, h, memory):
+            aux = jnp.zeros((), jnp.float32)
+            for b, block in stack.blocks():
+                h, a = block.apply(stack.part(lp, b), h, stack.cfg,
+                                   memory=memory)
+                aux = aux + a
+            return h, aux
+
+        if stack.remat and cfg.remat != "none":
+            policy = (jax.checkpoint_policies.nothing_saveable
+                      if cfg.remat == "full"
+                      else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            layer = jax.checkpoint(layer, policy=policy, static_argnums=())
+
+        def body(carry, lp):
+            h, aux = carry
+            h, a = layer(lp, h, memory)
+            return (h, aux + a), None
+
+        with scope("layers"):
+            (x, aux), _ = self._walk(body, (x, jnp.zeros((), jnp.float32)),
+                                     params)
+        return x, aux
+
+    def _step(self, stack: Stack, params, cache, x, step: Callable):
+        """x through the stack's layers with their cache, ``step(block, p,
+        x, c, cfg) -> (x, new c)`` for each block -> (x, the new stacked
+        cache)."""
+        def body(h, inp):
+            lp, lc = inp
+            new = {}
+            for b, block in stack.blocks():
+                h, new[b] = step(block, stack.part(lp, b), h,
+                                 stack.part(lc, b), stack.cfg)
+            return h, stack.layer(new)
+
+        with scope("layers"):
+            return self._walk(body, x, (params, cache))
 
     # -------------------------------------------------------------- forward
     @scope("embed")
@@ -108,75 +209,29 @@ class LanguageModel:
         x = rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
         return self._logits(params, x)
 
-    def _group_apply(self, gparams, x, memory):
+    def _memory(self, params, memory_embeds):
+        """The memory the cross-attention blocks read: the encoder's output
+        for enc-dec models, else the stub frontend's embeddings (or
+        None)."""
         cfg = self.cfg
-        aux = jnp.zeros((), jnp.float32)
-        for i, kind in enumerate(cfg.pattern):
-            x, a = REGISTRY[kind].apply(gparams[f"b{i}"], x, cfg,
-                                        memory=memory)
-            aux = aux + a
-        return x, aux
+        if cfg.enc_layers > 0:
+            assert memory_embeds is not None, "enc-dec model needs frames"
+            x = memory_embeds.astype(dtype_of(cfg))
+            x, _ = self._run(self._encoder(), params["encoder"]["blocks"], x)
+            return rmsnorm(x, params["encoder"]["final_norm"], cfg.norm_eps)
+        if memory_embeds is not None:
+            return memory_embeds.astype(dtype_of(cfg))
+        return None
 
-    @scope("layers")
-    def _run_groups(self, params, x, memory):
-        cfg = self.cfg
-        apply = self._group_apply
-        if cfg.remat != "none":
-            policy = (jax.checkpoint_policies.nothing_saveable
-                      if cfg.remat == "full"
-                      else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-            apply = jax.checkpoint(apply, policy=policy,
-                                   static_argnums=())
-        if cfg.scan_layers:
-            def body(carry, gparams):
-                h, aux = carry
-                h, a = apply(gparams, h, memory)
-                return (h, aux + a), None
-            (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       params["groups"])
-        else:
-            aux = jnp.zeros((), jnp.float32)
-            for g in range(cfg.n_groups):
-                gp = jax.tree.map(lambda a: a[g], params["groups"])
-                x, a = apply(gp, x, memory)
-                aux = aux + a
-        return x, aux
-
-    @scope("layers")
-    def _run_prefix(self, params, x):
-        cfg = self.cfg
-        if cfg.first_dense == 0:
-            return x
-        dense_cfg = self._prefix_cfg()
-        block = REGISTRY[cfg.prefix_kind]
-
-        if cfg.scan_layers:
-            def body(h, bparams):
-                h, _ = block.apply(bparams, h, dense_cfg)
-                return h, None
-            x, _ = jax.lax.scan(body, x, params["prefix"])
-        else:
-            for i in range(cfg.first_dense):
-                bp = jax.tree.map(lambda a: a[i], params["prefix"])
-                x, _ = block.apply(bp, x, dense_cfg)
-        return x
-
-    def _encode(self, params, frame_embeds):
-        cfg = self.cfg
-        x = frame_embeds.astype(dtype_of(cfg))
-
-        with scope("layers"):
-            if cfg.scan_layers:
-                def body(h, bparams):
-                    h, _ = REGISTRY["enc_attn"].apply(bparams, h, cfg)
-                    return h, None
-                x, _ = jax.lax.scan(body, x, params["encoder"]["blocks"])
-            else:
-                for i in range(cfg.enc_layers):
-                    bp = jax.tree.map(lambda a: a[i],
-                                      params["encoder"]["blocks"])
-                    x, _ = REGISTRY["enc_attn"].apply(bp, x, cfg)
-        return rmsnorm(x, params["encoder"]["final_norm"], cfg.norm_eps)
+    def _trunk(self, params, tokens, memory_embeds=None):
+        """tokens through the decoder's layers -> (hidden states before the
+        head, aux loss of the pattern groups)."""
+        memory = self._memory(params, memory_embeds)
+        x = self._embed(params, tokens)
+        stacks = self._stacks()
+        if "prefix" in stacks:
+            x, _ = self._run(stacks["prefix"], params["prefix"], x)
+        return self._run(stacks["groups"], params["groups"], x, memory)
 
     def forward(self, params, tokens, *, memory_embeds=None
                 ) -> Tuple[jax.Array, jax.Array]:
@@ -186,17 +241,7 @@ class LanguageModel:
         image patches) for audio/vlm families; encoder runs here for
         enc-dec models.
         """
-        cfg = self.cfg
-        memory = None
-        if cfg.enc_layers > 0:
-            assert memory_embeds is not None, "enc-dec model needs frames"
-            memory = self._encode(params, memory_embeds)
-        elif memory_embeds is not None:
-            memory = memory_embeds.astype(dtype_of(cfg))
-
-        x = self._embed(params, tokens)
-        x = self._run_prefix(params, x)
-        x, aux = self._run_groups(params, x, memory)
+        x, aux = self._trunk(params, tokens, memory_embeds)
         return self._head(params, x), aux
 
     # ----------------------------------------------------------------- loss
@@ -204,20 +249,9 @@ class LanguageModel:
         """batch: tokens (B,S), labels (B,S) (-100 = ignore), optional
         memory_embeds."""
         cfg = self.cfg
-        trunk = None
-        if cfg.mtp_depth > 0 and cfg.mtp_share_trunk:
-            # §Perf: compute the trunk ONCE; head + MTP both read it
-            memory = None
-            if batch.get("memory_embeds") is not None:
-                memory = batch["memory_embeds"].astype(dtype_of(cfg))
-            x = self._embed(params, batch["tokens"])
-            x = self._run_prefix(params, x)
-            trunk, aux = self._run_groups(params, x, memory)
-            logits = self._head(params, trunk)
-        else:
-            logits, aux = self.forward(
-                params, batch["tokens"],
-                memory_embeds=batch.get("memory_embeds"))
+        trunk, aux = self._trunk(params, batch["tokens"],
+                                 batch.get("memory_embeds"))
+        logits = self._head(params, trunk)
         labels = batch["labels"]
         valid = labels >= 0
         safe = jnp.where(valid, labels, 0)
@@ -229,23 +263,16 @@ class LanguageModel:
         loss = xent + aux
 
         if cfg.mtp_depth > 0:
-            loss = loss + 0.3 * self._mtp_loss(params, batch, metrics,
-                                               trunk=trunk)
+            loss = loss + 0.3 * self._mtp_loss(params, batch, metrics, trunk)
         return loss, metrics
 
-    def _mtp_loss(self, params, batch, metrics, trunk=None) -> jax.Array:
+    def _mtp_loss(self, params, batch, metrics, trunk) -> jax.Array:
         """DeepSeek-V3 multi-token prediction: predict t+2 from a fused
-        (h_t, emb_{t+1}) stream through one extra block."""
+        (h_t, emb_{t+1}) stream through one extra block; h_t is the
+        main stream's ``trunk``."""
         cfg = self.cfg
         tokens, labels = batch["tokens"], batch["labels"]
-        if trunk is None:
-            # hidden states (pre-head) for the main stream
-            x = self._embed(params, tokens)
-            x = self._run_prefix(params, x)
-            x, _ = self._run_groups(params, x, None)
-        else:
-            x = trunk
-        h = rmsnorm(x, params["mtp"]["norm_h"], cfg.norm_eps)
+        h = rmsnorm(trunk, params["mtp"]["norm_h"], cfg.norm_eps)
         e_next = rmsnorm(self._embed(params, jnp.roll(tokens, -1, axis=1)),
                          params["mtp"]["norm_e"], cfg.norm_eps)
         fused = jnp.concatenate([h, e_next], axis=-1) \
@@ -268,78 +295,29 @@ class LanguageModel:
     # --------------------------------------------------------------- decode
     def init_cache(self, batch: int, max_len: int, *, abstract: bool = False
                    ) -> Dict:
-        cfg = self.cfg
-
-        def group_cache():
-            return {f"b{i}": REGISTRY[kind].cache(cfg, batch, max_len)
-                    for i, kind in enumerate(cfg.pattern)}
-
-        proto = jax.eval_shape(group_cache)
-        stack = (lambda a: jax.ShapeDtypeStruct((cfg.n_groups,) + a.shape,
-                                                a.dtype)) if abstract else \
-                (lambda a: jnp.zeros((cfg.n_groups,) + a.shape, a.dtype))
-        cache: Dict[str, Any] = {"groups": jax.tree.map(stack, proto)}
-        if cfg.first_dense > 0:
-            pre = jax.eval_shape(
-                lambda: REGISTRY[cfg.prefix_kind].cache(cfg, batch, max_len))
-            stack_p = (lambda a: jax.ShapeDtypeStruct(
-                (cfg.first_dense,) + a.shape, a.dtype)) if abstract else \
-                (lambda a: jnp.zeros((cfg.first_dense,) + a.shape, a.dtype))
-            cache["prefix"] = jax.tree.map(stack_p, pre)
+        cache: Dict[str, Any] = {}
+        for name, stack in self._stacks().items():
+            proto = jax.eval_shape(lambda: stack.cache(batch, max_len))
+            n = stack.count
+            cache[name] = jax.tree.map(
+                (lambda a: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype))
+                if abstract else
+                (lambda a: jnp.zeros((n,) + a.shape, a.dtype)), proto)
         return cache
 
     def decode_step(self, params, cache: Dict, tokens, pos, *,
                     memory_embeds=None) -> Tuple[jax.Array, Dict]:
         """tokens: (B, 1); pos: scalar int32 -> (logits (B, V), new cache)."""
-        cfg = self.cfg
-        memory = None
-        if cfg.enc_layers > 0:
-            assert memory_embeds is not None
-            memory = self._encode(params, memory_embeds)
-        elif memory_embeds is not None:
-            memory = memory_embeds.astype(dtype_of(cfg))
-
+        memory = self._memory(params, memory_embeds)
         x = self._embed(params, tokens)
         new_cache: Dict[str, Any] = {}
-
-        if cfg.first_dense > 0:
-            dense_cfg = self._prefix_cfg()
-
-            def pre_body(h, inp):
-                bp, bc = inp
-                return REGISTRY[cfg.prefix_kind].decode(bp, h, bc, pos,
-                                                        dense_cfg)
-
-            with scope("layers"):
-                x, new_cache["prefix"] = self._decode_layers(
-                    pre_body, x, params["prefix"], cache["prefix"],
-                    cfg.first_dense)
-
-        def body(h, inp):
-            gp, gc = inp
-            ncs = {}
-            for i, kind in enumerate(cfg.pattern):
-                h, nc = REGISTRY[kind].decode(gp[f"b{i}"], h, gc[f"b{i}"],
-                                              pos, cfg, memory=memory)
-                ncs[f"b{i}"] = nc
-            return h, ncs
-
-        with scope("layers"):
-            x, new_cache["groups"] = self._decode_layers(
-                body, x, params["groups"], cache["groups"], cfg.n_groups)
+        for name, stack in self._stacks().items():
+            x, new_cache[name] = self._step(
+                stack, params[name], cache[name], x,
+                lambda block, p, h, c, cfg: block.decode(
+                    p, h, c, pos, cfg, memory=memory))
         logits = self._head(params, x)[:, 0, :]
         return logits, new_cache
-
-    def _decode_layers(self, body, x, params, cache, n: int):
-        """``body`` over the stacked layers' params and cache: scanned, or
-        unrolled; returns x and the new stacked cache."""
-        if self.cfg.scan_layers:
-            return jax.lax.scan(body, x, (params, cache))
-        ncs = []
-        for i in range(n):
-            x, nc = body(x, jax.tree.map(lambda a: a[i], (params, cache)))
-            ncs.append(nc)
-        return x, jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
 
     def prefill(self, params, tokens, cache: Dict, *, memory_embeds=None):
         """tokens: (B, S) prompt; cache from ``init_cache`` -> (logits of
@@ -358,10 +336,9 @@ class LanguageModel:
         Each trace counts its path in
         ``repro_jax_prefill_lowerings_total{path}``."""
         cfg = self.cfg
-        kinds = cfg.pattern + ((cfg.prefix_kind,) if cfg.first_dense > 0
-                               else ())
         sequence = (memory_embeds is None and cfg.enc_layers == 0
-                    and all(REGISTRY[k].prefill for k in kinds))
+                    and all(block.prefill for stack in self._stacks().values()
+                            for _, block in stack.blocks()))
         _prefill_lowerings("sequence" if sequence else "token").inc()
         if sequence:
             return self._prefill_sequence(params, tokens, cache)
@@ -369,34 +346,12 @@ class LanguageModel:
                                     memory_embeds=memory_embeds)
 
     def _prefill_sequence(self, params, tokens, cache: Dict):
-        cfg = self.cfg
         x = self._embed(params, tokens)
         new_cache: Dict[str, Any] = {}
-
-        if cfg.first_dense > 0:
-            dense_cfg = self._prefix_cfg()
-
-            def pre_body(h, inp):
-                bp, bc = inp
-                return REGISTRY[cfg.prefix_kind].prefill(bp, h, bc,
-                                                         dense_cfg)
-
-            with scope("layers"):
-                x, new_cache["prefix"] = self._decode_layers(
-                    pre_body, x, params["prefix"], cache["prefix"],
-                    cfg.first_dense)
-
-        def body(h, inp):
-            gp, gc = inp
-            ncs = {}
-            for i, kind in enumerate(cfg.pattern):
-                h, ncs[f"b{i}"] = REGISTRY[kind].prefill(
-                    gp[f"b{i}"], h, gc[f"b{i}"], cfg)
-            return h, ncs
-
-        with scope("layers"):
-            x, new_cache["groups"] = self._decode_layers(
-                body, x, params["groups"], cache["groups"], cfg.n_groups)
+        for name, stack in self._stacks().items():
+            x, new_cache[name] = self._step(
+                stack, params[name], cache[name], x,
+                lambda block, p, h, c, cfg: block.prefill(p, h, c, cfg))
         logits = self._head(params, x[:, -1:, :])[:, 0, :]
         return logits, new_cache
 

@@ -7,10 +7,10 @@ force XLA to re-gather the whole activation (found in the dry-run: 3e14
 collective bytes on train_4k).  B/C are small (2N per token) and computed
 replicated; z/x/dt shard cleanly on heads/channels.
 
-Uses the Pallas SSD kernel (TPU target) or the chunked-jnp path with
-head-block processing (XLA fallback; see kernels/ssd/ref.py).  The prefill
-(``ssm_prefill``) always takes the chunked-jnp path, which can start from
-the cache's state and return the final one."""
+The SSD is the chunked-jnp path with head-block processing
+(kernels/ssd/ref.py); the prefill (``ssm_prefill``) starts it from the
+cache's state and returns the final one.  The Pallas SSD kernel
+(kernels/ssd) is not on the model's path."""
 from __future__ import annotations
 
 from typing import Dict, Tuple
@@ -153,13 +153,13 @@ def _ssm_sequence(p, x, cfg: ModelConfig, cache=None):
             bmat.astype(jnp.float32), cmat.astype(jnp.float32), cfg,
             mesh=mesh, dp_axes=dp_axes)
     else:
-        from ..kernels.ssd import ssd_scan
-        y = ssd_scan(xh.astype(jnp.float32), dt, p["a_log"],
-                     bmat.astype(jnp.float32), cmat.astype(jnp.float32),
-                     chunk=cfg.ssm_chunk,
-                     use_kernel=cfg.use_flash_kernel,
-                     unroll_heads=cfg.attn_chunk_unroll,
-                     head_blocks=hb)
+        from ..kernels.ssd.ref import ssd_chunked_jnp
+        y = ssd_chunked_jnp(xh.astype(jnp.float32), dt, p["a_log"],
+                            bmat.astype(jnp.float32),
+                            cmat.astype(jnp.float32),
+                            chunk=min(cfg.ssm_chunk, s),
+                            unroll_heads=cfg.attn_chunk_unroll,
+                            head_blocks=hb)
     y = y + xh.astype(jnp.float32) * p["d_skip"][None, None, :, None]
     y = y.reshape(b, s, di).astype(dt_)
     y = rmsnorm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
@@ -245,8 +245,8 @@ def ssm_decode(p, x, cache: Dict, pos, cfg: ModelConfig
 # "model", batch over the DP axes, B/C replicated over "model".  Running the
 # chunked scan inside shard_map means autodiff inserts exactly ONE psum per
 # replicated input's gradient (dB, dC, dA) per layer — instead of GSPMD's
-# per-head-block (B,nc,L,L)-sized backward all-reduces (measured 6.8e13
-# collective bytes on mamba2 train_4k; see EXPERIMENTS.md §Perf).
+# per-head-block (B,nc,L,L)-sized backward all-reduces (6.8e13 collective
+# bytes in the dry run's cost analysis of mamba2 train_4k).
 # ---------------------------------------------------------------------------
 
 def _ssd_local_body(xh, dt, a_log, bmat, cmat, *, chunk: int,

@@ -14,22 +14,38 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import build
+from repro.models.blocks import REGISTRY
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_mtp_share_trunk_identical_loss():
+    """The MTP head reads the main stream's hidden states from the loss's
+    one pass through the trunk: its term is the MTP head over the trunk
+    re-run here, layer by layer."""
     cfg = get_config("deepseek-v3-671b", smoke=True)
-    model_base = build(cfg)
-    model_opt = build(cfg.replace(mtp_share_trunk=True))
-    params = model_base.init(jax.random.PRNGKey(0))
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
     batch = {"tokens": toks, "labels": toks}
-    l0, m0 = model_base.loss_fn(params, batch)
-    l1, m1 = model_opt.loss_fn(params, batch)
-    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
-    np.testing.assert_allclose(float(m0["mtp"]), float(m1["mtp"]),
+    loss, m = model.loss_fn(params, batch)
+
+    x = model._embed(params, toks)
+    dense = cfg.replace(pattern=(cfg.prefix_kind,))
+    for i in range(cfg.first_dense):
+        x, _ = REGISTRY[cfg.prefix_kind].apply(
+            jax.tree.map(lambda a: a[i], params["prefix"]), x, dense)
+    for g in range(cfg.n_groups):
+        gp = jax.tree.map(lambda a: a[g], params["groups"])
+        for j, kind in enumerate(cfg.pattern):
+            x, _ = REGISTRY[kind].apply(gp[f"b{j}"], x, cfg)
+    rerun = {}
+    model._mtp_loss(params, batch, rerun, x)
+    np.testing.assert_allclose(float(m["mtp"]), float(rerun["mtp"]),
                                rtol=1e-5)
+    np.testing.assert_allclose(
+        float(loss), float(m["xent"] + m["aux"] + 0.3 * m["mtp"]),
+        rtol=1e-5)
 
 
 def test_ssd_shard_map_matches_gspmd():

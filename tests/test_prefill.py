@@ -203,7 +203,7 @@ def ssm_apply_before(p, x, cfg):
                          + p["dt_bias"][None, None, :])
     y = ssd_scan(xh.astype(jnp.float32), dt, p["a_log"],
                  bmat.astype(jnp.float32), cmat.astype(jnp.float32),
-                 chunk=cfg.ssm_chunk, use_kernel=cfg.use_flash_kernel,
+                 chunk=cfg.ssm_chunk, use_kernel=False,
                  unroll_heads=cfg.attn_chunk_unroll, head_blocks=h)
     y = y + xh.astype(jnp.float32) * p["d_skip"][None, None, :, None]
     y = y.reshape(b, s, di).astype(dt_)
